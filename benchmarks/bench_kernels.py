@@ -1,5 +1,8 @@
-"""Pallas kernel microbenchmarks (interpret mode on CPU: correctness-path
-timing, not TPU performance — TPU perf is the §Roofline story)."""
+"""Pallas kernel microbenchmarks, compiled on a TPU.
+
+Each row names the device it ran on.  Off the TPU the kernels would only
+run through the Pallas interpreter, whose time says nothing about the
+kernel, so every row then reads "not measured"."""
 
 from __future__ import annotations
 
@@ -11,6 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+
+NAMES = ("kernel/maxplus_256", "kernel/systolic_gemm_256",
+         "kernel/flash_attn_256")
 
 
 def _time(fn, *args, reps=3):
@@ -24,19 +30,23 @@ def _time(fn, *args, reps=3):
 
 
 def run(rows: List[Dict]) -> None:
+    dev = jax.devices()[0]
+    device = (f"platform={dev.platform};kind={dev.device_kind};"
+              f"count={jax.device_count()}")
+    if dev.platform != "tpu":
+        rows.extend({"name": n, "us_per_call": 0.0,
+                     "derived": f"{device};not measured"} for n in NAMES)
+        return
     rng = np.random.default_rng(0)
     A = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
     B = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
-    dt = _time(lambda a, b: ops.maxplus_matmul(a, b, bm=64, bk=64, bn=64), A, B)
-    rows.append({"name": "kernel/maxplus_256", "us_per_call": dt * 1e6,
-                 "derived": "interpret=True"})
-
-    Ab = A.astype(jnp.bfloat16); Bb = B.astype(jnp.bfloat16)
-    dt = _time(lambda a, b: ops.gemm(a, b, bm=64, bk=64, bn=64), Ab, Bb)
-    rows.append({"name": "kernel/systolic_gemm_256", "us_per_call": dt * 1e6,
-                 "derived": "interpret=True;bf16"})
-
+    Ab, Bb = A.astype(jnp.bfloat16), B.astype(jnp.bfloat16)
     q = jnp.asarray(rng.normal(size=(4, 256, 64)), jnp.float32)
-    dt = _time(lambda x: ops.flash_attention(x, x, x, bq=64, bk=64), q)
-    rows.append({"name": "kernel/flash_attn_256", "us_per_call": dt * 1e6,
-                 "derived": "interpret=True"})
+    timings = [
+        (_time(lambda a, b: ops.maxplus_matmul(a, b), A, B), "f32"),
+        (_time(lambda a, b: ops.gemm(a, b), Ab, Bb), "bf16"),
+        (_time(lambda x: ops.flash_attention(x, x, x), q), "f32"),
+    ]
+    for name, (dt, dtype) in zip(NAMES, timings):
+        rows.append({"name": name, "us_per_call": dt * 1e6,
+                     "derived": f"{device};{dtype}"})

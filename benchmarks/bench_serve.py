@@ -38,13 +38,13 @@ Sections:
   time-to-recovery.
 * ``serve/sharded`` — ``PackedMatrix.evaluate(sharded=True)`` vs the
   single-device path on the same candidate batch: devices used, both
-  throughputs, speedup, and bitwise agreement (always asserted).  When
-  the process only sees one device, the probe re-runs itself in a
-  subprocess under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-  so the sharded code path is always exercised.  The > 2x speedup floor
-  is asserted only when the host has >= 8 physical cores — forced host
-  devices on fewer cores time-slice the same silicon, so the speedup is
-  real parallelism there, not on a 1-core CI box.
+  throughputs, speedup, and bitwise agreement (always asserted).  A
+  process that sees one device reports the row as "not measured"; run
+  under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to
+  exercise the path on a CPU host.  The > 2x speedup floor is asserted
+  only when the host has >= 8 physical cores — forced host devices on
+  fewer cores time-slice the same silicon, so the speedup is real
+  parallelism there, not on a 1-core CI box.
 
 Budget: ``BENCH_BUDGET=small`` shrinks the pool / stream (same code
 paths); rows are recorded via ``python -m benchmarks.run --only serve
@@ -53,11 +53,7 @@ paths); rows are recorded via ``python -m benchmarks.run --only serve
 
 from __future__ import annotations
 
-import json
 import os
-import pathlib
-import subprocess
-import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List
@@ -65,7 +61,6 @@ from typing import Dict, List
 import numpy as np
 
 SMALL = os.environ.get("BENCH_BUDGET", "").lower() == "small"
-REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _query_stream(ex) -> List:
@@ -325,18 +320,24 @@ def _bench_faults(rows: List[Dict], ex, bundle) -> None:
 
 # -- sharded probe ----------------------------------------------------------
 
-def _sharded_payload() -> Dict:
-    """Single-device vs candidate-sharded PackedMatrix throughput under
-    whatever device count THIS process sees; runs in the bench process
-    when it already has multiple devices, or in the forced-8-device
-    subprocess below."""
+def _bench_sharded(rows: List[Dict]) -> None:
+    """Single-device vs candidate-sharded PackedMatrix throughput over the
+    devices THIS process sees.  With one device there is nothing to shard
+    and the row says "not measured": starting a child process to force
+    host devices would need the device the parent already holds (run the
+    whole bench under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+    to exercise the path on a CPU host)."""
     import jax
 
     from repro.core.aidg.explorer import Explorer, random_candidates
 
+    D = jax.local_device_count()
+    if D == 1:
+        rows.append({"name": "serve/sharded", "us_per_call": 0.0,
+                     "derived": "devices=1;not measured"})
+        return
     ex = Explorer()
     pm = ex.packed_matrix()
-    D = pm.n_shards(None)
     B = -(-(64 if SMALL else 512) // D) * D
     cand = random_candidates(ex.space, B, seed=0)
 
@@ -354,63 +355,26 @@ def _sharded_payload() -> Dict:
     exact = bool(np.array_equal(pm.evaluate(cand),
                                 pm.evaluate(cand, sharded=True)))
     configs = B * pm.n_cells
-    return {"devices": D, "batch": B, "cells": int(pm.n_cells),
-            "single_configs_per_s": configs / t_single,
-            "sharded_configs_per_s": configs / t_shard,
-            "speedup": t_single / t_shard, "exact": exact,
-            "jax_devices": jax.local_device_count()}
-
-
-def _sharded_probe_subprocess(n_devices: int = 8) -> Dict:
-    """Re-run :func:`_sharded_payload` in a child process with
-    ``--xla_force_host_platform_device_count`` set (the flag only takes
-    effect before the first jax import, so the parent can't apply it to
-    itself); the child prints the payload as its last stdout line."""
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (flags + " " if flags else "") + \
-        f"--xla_force_host_platform_device_count={n_devices}"
-    src = str(REPO_ROOT / "src")
-    old = env.get("PYTHONPATH", "")
-    env["PYTHONPATH"] = src + (os.pathsep + old if old else "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_serve", "--sharded-probe"],
-        cwd=str(REPO_ROOT), env=env, capture_output=True, text=True,
-        timeout=1200)
-    if proc.returncode != 0:
-        raise AssertionError(
-            f"sharded probe subprocess failed:\n{proc.stderr[-2000:]}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-def _bench_sharded(rows: List[Dict]) -> None:
-    import jax
-
-    if jax.local_device_count() > 1:
-        payload = _sharded_payload()
-    else:
-        payload = _sharded_probe_subprocess(8)
+    speedup = t_single / t_shard
     rows.append({"name": "serve/sharded", "us_per_call": 0.0,
-                 "derived": (f"devices={payload['devices']};"
-                             f"batch={payload['batch']};"
-                             f"cells={payload['cells']};"
+                 "derived": (f"devices={D};batch={B};cells={pm.n_cells};"
                              f"single_configs_per_s="
-                             f"{payload['single_configs_per_s']:.0f};"
+                             f"{configs / t_single:.0f};"
                              f"sharded_configs_per_s="
-                             f"{payload['sharded_configs_per_s']:.0f};"
-                             f"speedup={payload['speedup']:.2f}x;"
-                             f"exact={payload['exact']};"
+                             f"{configs / t_shard:.0f};"
+                             f"speedup={speedup:.2f}x;"
+                             f"exact={exact};"
                              f"host_cores={os.cpu_count()}")})
-    if not payload["exact"]:
+    if not exact:
         raise AssertionError(
             "sharded evaluation is not bitwise-equal to single-device")
     cores = os.cpu_count() or 1
-    if cores >= 8 and payload["speedup"] < 2.0:
+    if cores >= 8 and speedup < 2.0:
         # forced host devices only parallelize when cores back them; on
         # a >= 8-core host a sub-2x sharded path is a real regression
         raise AssertionError(
-            f"sharded speedup {payload['speedup']:.2f}x < 2x on "
-            f"{payload['devices']} devices / {cores} cores")
+            f"sharded speedup {speedup:.2f}x < 2x on {D} devices / "
+            f"{cores} cores")
 
 
 def run(rows: List[Dict]) -> None:
@@ -425,11 +389,8 @@ def run(rows: List[Dict]) -> None:
 
 
 if __name__ == "__main__":
-    if "--sharded-probe" in sys.argv:
-        print(json.dumps(_sharded_payload()))
-    else:
-        rows: List[Dict] = []
-        run(rows)
-        print("name,us_per_call,derived")
-        for r in rows:
-            print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
+    rows: List[Dict] = []
+    run(rows)
+    print("name,us_per_call,derived")
+    for r in rows:
+        print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")

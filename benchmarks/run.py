@@ -73,8 +73,12 @@ def write_json(section: str, rows: List[Dict], out_dir: str) -> str:
 
 
 def main(argv: List[str] = None) -> int:
+    from repro.compile_cache import enable_compile_cache
+
     from . import (bench_dse, bench_kernels, bench_paper, bench_serve,
                    bench_workloads)
+
+    enable_compile_cache()
 
     sections = {"paper": bench_paper, "dse": bench_dse,
                 "workloads": bench_workloads, "kernels": bench_kernels,

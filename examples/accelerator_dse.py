@@ -23,6 +23,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.aidg.explorer import (Explorer, grid_candidates,
                                       random_candidates)
 from repro.core.aidg.gradient import GradientExplorer
@@ -161,4 +162,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

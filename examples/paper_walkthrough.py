@@ -11,6 +11,7 @@ and §6's timing simulation + the AIDG fast path ([16]).
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.acadl import simulate
 from repro.core.aidg import estimate_cycles
 from repro.core.archs import make_gamma_ag, make_oma_ag, make_systolic_ag
@@ -76,4 +77,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -8,6 +8,7 @@ whole network.
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.core.aidg import estimate_cycles
 from repro.core.archs import TPU_V5E, make_tpu_v5e_ag
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models import get_model
 
@@ -55,4 +56,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

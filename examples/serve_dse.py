@@ -42,6 +42,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.aidg.explorer import Explorer
 from repro.serve import (CircuitBreaker, DSEService, FaultPlan, Query,
                          RetryPolicy, ServeError, WorkerKill)
@@ -305,4 +306,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -519,6 +519,23 @@ def _stage_row(prob: DSEProblem, proj, k_prologue: int) -> _PackedRow:
         queues=queues)
 
 
+def _sum_last(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis in a fixed pairwise order.  XLA leaves the
+    order of a ``reduce`` to each backend, and network cells sum run
+    makespans past 2**24 where the order decides the rounding; halving
+    with elementwise adds rounds the same on the TPU and on the host (and
+    keeps the energy matvec off the MXU's reduced-precision passes)."""
+    n = x.shape[-1]
+    width = 1 << max(0, n - 1).bit_length()
+    if width > n:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (width - n,), x.dtype)], axis=-1)
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 class PackedMatrix:
     """The whole scenario/network matrix as ONE traced evaluator.
 
@@ -946,10 +963,11 @@ class PackedMatrix:
         fully traced: one vmapped wavefront fixed point per shape bucket
         (all inside the one trace), bucket outputs re-ordered to global
         rows, then the run-length composition per cell.  The energy
-        objective rides the SAME trace — one pre-folded matvec
-        ``edyn @ (1/θ)`` plus the static term ``P_static · cycles`` — so a
-        3-objective evaluation is still a single dispatch with no second
-        pass."""
+        objective rides the SAME trace — the pre-folded dynamic term
+        ``Σₖ edynₖ / θₖ`` plus the static term ``P_static · cycles`` — so
+        a 3-objective evaluation is still a single dispatch with no second
+        pass.  Every sum over a cell's runs or knobs goes through
+        :func:`_sum_last`, so the TPU and the host round alike."""
         A = self._build_arrays()
 
         def bucket_args(BA):
@@ -980,17 +998,17 @@ class PackedMatrix:
             mr, pr = m[runs], p[runs]
             clip = ((lambda a, b: -softmaximum(-a, -b, tau)) if soft
                     else jnp.minimum)
-            total = (reps * mr).sum(axis=-1)
-            within = ((reps - 1.0) * clip(pr, mr) * fw).sum(axis=-1)
+            total = _sum_last(reps * mr)
+            within = _sum_last((reps - 1.0) * clip(pr, mr) * fw)
             if RU > 1:
-                between = (clip(pr[:, 1:], mr[:, :-1]) * fb).sum(axis=-1)
+                between = _sum_last(clip(pr[:, 1:], mr[:, :-1]) * fb)
             else:
                 between = 0.0
             cycles = total - within - between
             # DVFS-style dynamic term (faster units burn more pJ per op)
             # plus leakage over the makespan — analytic in θ, and the
             # static part differentiates through the soft makespan
-            energy = edyn @ (1.0 / kn) + pstat * cycles
+            energy = _sum_last(edyn * (1.0 / kn)) + pstat * cycles
             return cycles, energy
 
         return fn
@@ -1030,34 +1048,27 @@ class PackedMatrix:
     def sharded_fn(self, n_devices: Optional[int] = None) -> Callable:
         """Cached device-sharded hard evaluator: ``fn(knobs (B, K)) ->
         ((B, S) cycles, (B, S) energy)`` with the CANDIDATE axis split
-        across ``n_shards`` devices via ``shard_map`` (``pmap`` fallback
-        on JAX builds without it) — each device runs the same vmapped
-        packed evaluator over its B/D slice, so results are bitwise
-        identical to the single-device path (per-candidate rows are
-        independent; asserted by ``tests/test_serve.py``).  B must be a
-        multiple of the device count — ``evaluate(sharded=True)`` pads
-        for you."""
+        across ``n_shards`` devices via ``jax.shard_map`` — each device
+        runs the same vmapped packed evaluator over its B/D slice, so
+        results are bitwise identical to the single-device path
+        (per-candidate rows are independent; asserted by
+        ``tests/test_serve.py``).  B must be a multiple of the device
+        count — ``evaluate(sharded=True)`` pads for you."""
         D = self.n_shards(n_devices)
         key = ("sharded", D)
         fn = self._compiled.get(key)
         if fn is None:
+            from jax.sharding import Mesh, PartitionSpec as P
             f = self._matrix_fn(soft=False)
             batched = jax.vmap(lambda k: f(k, jnp.float32(1.0)))
-            devices = jax.local_devices()[:D]
-            try:
-                from jax.experimental.shard_map import shard_map
-                from jax.sharding import Mesh, PartitionSpec as P
-                mesh = Mesh(np.asarray(devices), ("cand",))
-                fn = jax.jit(shard_map(batched, mesh=mesh,
+            mesh = Mesh(np.asarray(jax.local_devices()[:D]), ("cand",))
+            # check_vma=False: the body is the unsharded evaluator, whose
+            # scan carries start as replicated constants and come out
+            # varying over "cand"; the per-device program is unchanged
+            fn = jax.jit(jax.shard_map(batched, mesh=mesh,
                                        in_specs=P("cand"),
-                                       out_specs=(P("cand"), P("cand"))))
-            except ImportError:       # pre-shard_map JAX: explicit pmap
-                pfn = jax.pmap(batched, devices=devices)
-
-                def fn(kt, _pfn=pfn, _D=D):
-                    B = kt.shape[0]
-                    c, en = _pfn(kt.reshape(_D, B // _D, kt.shape[1]))
-                    return c.reshape(B, -1), en.reshape(B, -1)
+                                       out_specs=(P("cand"), P("cand")),
+                                       check_vma=False))
             self._compiled[key] = fn
         return fn
 

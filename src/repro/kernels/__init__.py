@@ -5,9 +5,10 @@
                         adapted to the TPU memory hierarchy)
 * ``flash_attention`` — chunked online-softmax attention (prefill hot-spot)
 
-Each kernel: ``<name>.py`` (pl.pallas_call + BlockSpec), validated in
-``interpret=True`` mode against the pure-jnp oracles in ``ref.py``; public
-entry points with padding/fallback logic live in ``ops.py``.
+Each kernel: ``<name>.py`` (pl.pallas_call + BlockSpec), compiled for the
+TPU by default and validated on the CPU in ``interpret=True`` mode against
+the pure-jnp oracles in ``ref.py``; public entry points with padding logic
+live in ``ops.py``.
 """
 
 from . import ops, ref

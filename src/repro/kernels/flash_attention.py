@@ -76,7 +76,7 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            bq: int = 128, bk: int = 128, causal: bool = True,
                            window: int = 0, scale: float | None = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """(BH, Sq, D) x (BH, Sk, D) x (BH, Sk, D) -> (BH, Sq, D).
 
     Block sizes must divide the sequence lengths (ops.flash_attention pads).

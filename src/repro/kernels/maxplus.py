@@ -1,14 +1,18 @@
-"""Blocked max-plus matmul Pallas kernel.
+"""Blocked max-plus Pallas kernels.
 
 The AIDG longest-path relaxation is a max-plus matmul (DESIGN.md §4):
-``(A ⊗ B)_ij = max_k (A_ik + B_kj)``.  The kernel tiles exactly like an MXU
-matmul — (8, 128)-aligned VMEM blocks, k-innermost grid accumulation — but
-reduces with max/add on the VPU instead of mul/add on the MXU.
+``(A ⊗ B)_ij = max_k (A_ik + B_kj)``.  The matmul kernel tiles exactly like
+an MXU matmul — (8, 128)-aligned VMEM blocks, k-innermost grid accumulation
+— but reduces with max/add on the VPU instead of mul/add on the MXU.
 
-VMEM budget: the naive broadcast ``a[:, :, None] + b[None, :, :]`` would
-materialize a (bm, bk, bn) cube; instead the kernel walks the k block in
-``K_STEP``-deep slabs, keeping the working set at
-``bm*bk + bk*bn + bm*bn + K_STEP*bm*bn`` floats.
+Inside a block, each k step broadcasts column k of A across the lanes and
+row k of B across the sublanes.  The k loop is unrolled at trace time: a
+static column index is the only lane-axis slice Mosaic accepts that is not
+a multiple of 128.  The working set stays at ``bm*bk + bk*bn + bm*bn``
+floats.
+
+The matvec kernel takes A transposed, so the reduced k axis lies on the
+sublanes and the output is one lane-dense row.
 """
 
 from __future__ import annotations
@@ -23,7 +27,6 @@ __all__ = ["maxplus_matmul_kernel", "maxplus_matmul_pallas",
            "maxplus_matvec_pallas"]
 
 NEG = -1e18
-K_STEP = 8  # k-slab depth per VPU step inside a block
 
 
 def maxplus_matmul_kernel(a_ref, b_ref, o_ref, *, bk: int):
@@ -35,28 +38,18 @@ def maxplus_matmul_kernel(a_ref, b_ref, o_ref, *, bk: int):
 
     a = a_ref[...]            # (bm, bk)
     b = b_ref[...]            # (bk, bn)
-
-    if bk % K_STEP == 0 and bk > K_STEP:
-        def body(s, acc):
-            # (bm, K_STEP, 1) + (1, K_STEP, bn) -> max over the slab axis
-            a_slab = jax.lax.dynamic_slice_in_dim(a, s * K_STEP, K_STEP, axis=1)
-            b_slab = jax.lax.dynamic_slice_in_dim(b, s * K_STEP, K_STEP, axis=0)
-            cand = jnp.max(a_slab[:, :, None] + b_slab[None, :, :], axis=1)
-            return jnp.maximum(acc, cand)
-
-        acc = jax.lax.fori_loop(0, bk // K_STEP, body,
-                                jnp.full(o_ref.shape, NEG, o_ref.dtype))
-    else:  # tiny-k fallback: single broadcast slab
-        acc = jnp.max(a[:, :, None] + b[None, :, :], axis=1)
-    o_ref[...] = jnp.maximum(o_ref[...], acc)
+    acc = o_ref[...]
+    for kk in range(bk):
+        acc = jnp.maximum(acc, a[:, kk:kk + 1] + b[kk:kk + 1, :])
+    o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def maxplus_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                           bm: int = 128, bk: int = 128, bn: int = 128,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool = False) -> jnp.ndarray:
     """C = A ⊗ B for (M, K) ⊗ (K, N); shapes must divide the block sizes
-    (ops.pad_maxplus handles ragged shapes)."""
+    (``ops.maxplus_matmul`` pads ragged shapes)."""
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -76,9 +69,39 @@ def maxplus_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
     )(a.astype(jnp.float32), b.astype(jnp.float32))
 
 
-def maxplus_matvec_pallas(A: jnp.ndarray, v: jnp.ndarray, **kw) -> jnp.ndarray:
+def maxplus_matvec_kernel(at_ref, v_ref, o_ref):
+    """One lane-dense (1, bm) output row: ``max_k (Aᵀ_ki + v_k)`` reduced
+    over the sublane axis, accumulating max over the k grid axis."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.full_like(o_ref, NEG)
+
+    cand = jnp.max(at_ref[...] + v_ref[...], axis=0, keepdims=True)
+    o_ref[...] = jnp.maximum(o_ref[...], cand)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
+def maxplus_matvec_pallas(A: jnp.ndarray, v: jnp.ndarray, *,
+                          bm: int = 128, bk: int = 128,
+                          interpret: bool = False) -> jnp.ndarray:
     """(A ⊗ v)_i = max_k (A_ik + v_k) for (M, K) ⊗ (K,) — the per-block
     propagation step of the AIDG blocked evaluator
-    (``repro.core.aidg.maxplus.longest_path_blocked``) routed through the
-    Pallas kernel as a single-column matmul."""
-    return maxplus_matmul_pallas(A, v[:, None], **kw)[:, 0]
+    (``repro.core.aidg.maxplus.longest_path_blocked``).  Shapes must
+    divide the block sizes."""
+    m, k = A.shape
+    assert v.shape == (k,), (A.shape, v.shape)
+    bm, bk = min(bm, m), min(bk, k)
+    assert m % bm == 0 and k % bk == 0, (m, k, bm, bk)
+    out = pl.pallas_call(
+        maxplus_matvec_kernel,
+        grid=(m // bm, k // bk),
+        in_specs=[
+            pl.BlockSpec((bk, bm), lambda i, kk: (kk, i)),
+            pl.BlockSpec((bk, 1), lambda i, kk: (kk, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bm), lambda i, kk: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+        interpret=interpret,
+    )(A.T.astype(jnp.float32), v.astype(jnp.float32)[:, None])
+    return out[0]

@@ -1,25 +1,21 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle ragged shapes by padding to block multiples (max-plus pads with
--inf, gemm with zeros, attention with masked keys), pick block sizes that
-fit VMEM, and fall back to the pure-jnp reference for shapes where a kernel
-launch cannot pay for itself (tiny operands).
+-inf, gemm with zeros, attention with masked keys).  A shape the padding
+cannot make exact raises instead of quietly switching to the pure-jnp
+reference in ``ref.py``.
 
-``interpret=True`` is the default everywhere in this repo: the container is
-CPU-only and Pallas TPU kernels execute through the interpreter for
-correctness validation; on a real TPU backend pass ``interpret=False``.
+Every kernel runs compiled by default (``interpret=False``), which needs a
+TPU.  On the CPU pass ``interpret=True``: the kernel then runs through the
+Pallas interpreter, which checks results, not speed.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
-from . import ref
 from .flash_attention import flash_attention_pallas
 from .maxplus import maxplus_matmul_pallas
 from .selective_scan import selective_scan_pallas
@@ -42,7 +38,7 @@ def _pad_to(x: jnp.ndarray, mults, value) -> jnp.ndarray:
 
 def maxplus_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
                    bk: int = 128, bn: int = 128,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     """(A ⊗ B) with -inf padding for ragged shapes."""
     m, k = a.shape
     _, n = b.shape
@@ -55,7 +51,7 @@ def maxplus_matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
 
 def gemm(a: jnp.ndarray, b: jnp.ndarray, *, activation: int = 0,
          bm: int = 128, bk: int = 128, bn: int = 128,
-         out_dtype=jnp.float32, interpret: bool = True) -> jnp.ndarray:
+         out_dtype=jnp.float32, interpret: bool = False) -> jnp.ndarray:
     """act(A @ B) with zero padding for ragged shapes."""
     m, k = a.shape
     _, n = b.shape
@@ -71,14 +67,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 128,
                     scale: Optional[float] = None,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """Attention over (B, H, S, D) or (BH, S, D) inputs.
 
     Padded keys are masked through the causal/positional mask: key padding
     appends positions > every real query position, which the causal mask
-    excludes; for non-causal inputs padded keys are masked explicitly by
-    passing window=0 and relying on -inf score padding via key padding of
-    q-side only — non-causal ragged ``sk`` therefore falls back to ref.
+    excludes.  For non-causal inputs nothing masks them, so a non-causal
+    ``sk`` that is not a multiple of ``bk`` raises ``ValueError``.
     """
     squeeze = q.ndim == 4
     if squeeze:
@@ -88,23 +83,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         v = v.reshape(b * h, v.shape[2], d)
     bh, sq, d = q.shape
     _, sk, _ = k.shape
-    pq, pk = (-sq) % bq, (-sk) % bk
-    if pk and not causal:
-        out = ref.flash_attention_ref(q, k, v, causal=False, scale=scale)
-    else:
-        qp = _pad_to(q, (1, bq, 1), 0)
-        kp = _pad_to(k, (1, bk, 1), 0)
-        vp = _pad_to(v, (1, bk, 1), 0)
-        out = flash_attention_pallas(qp, kp, vp, bq=bq, bk=bk, causal=causal,
-                                     window=window, scale=scale,
-                                     interpret=interpret)[:, :sq]
+    if sk % bk and not causal:
+        raise ValueError(f"non-causal attention needs sk ({sk}) to be a "
+                         f"multiple of bk ({bk}): padded keys are unmasked")
+    qp = _pad_to(q, (1, bq, 1), 0)
+    kp = _pad_to(k, (1, bk, 1), 0)
+    vp = _pad_to(v, (1, bk, 1), 0)
+    out = flash_attention_pallas(qp, kp, vp, bq=bq, bk=bk, causal=causal,
+                                 window=window, scale=scale,
+                                 interpret=interpret)[:, :sq]
     if squeeze:
         out = out.reshape(b, h, sq, d)
     return out
 
 
 def selective_scan(x, dt, b, c, a, d, *, bd: int = 128,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """Mamba-1 selective scan; pads the channel dim to the block size."""
     B, S, D = x.shape
     p = (-D) % bd if D > bd else 0

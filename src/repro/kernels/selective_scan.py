@@ -49,7 +49,7 @@ def selective_scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
 @functools.partial(jax.jit, static_argnames=("bd", "interpret"))
 def selective_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, b: jnp.ndarray,
                           c: jnp.ndarray, a: jnp.ndarray, d: jnp.ndarray, *,
-                          bd: int = 128, interpret: bool = True) -> jnp.ndarray:
+                          bd: int = 128, interpret: bool = False) -> jnp.ndarray:
     """x/dt: (B, S, D); b/c: (B, S, N); a: (D, N); d: (D,) -> y (B, S, D)."""
     B, S, Dm = x.shape
     N = b.shape[-1]

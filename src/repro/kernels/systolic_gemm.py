@@ -51,7 +51,7 @@ def systolic_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, activation: int,
 def systolic_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                          bm: int = 128, bk: int = 128, bn: int = 128,
                          activation: int = 0, out_dtype=jnp.float32,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """C = act(A @ B); (M, K) @ (K, N), block sizes must divide the shapes
     (ops.systolic_gemm pads ragged inputs)."""
     m, k = a.shape

@@ -19,7 +19,7 @@ different collective algorithms than TPU's, so the collective term is a
 *structural estimate* (bytes over link bandwidth), not a measurement.
 
 HLO text format assumptions (post-optimization HLO, verified against
-jax 0.4.x / XLA:CPU):
+jax 0.4.x and 0.9.0 / XLA:CPU):
 
 - Instruction lines: ``[ROOT] %name = type{layout} op(...), attrs`` — the
   result type precedes the op name; operands may appear either bare

@@ -62,28 +62,6 @@ def _gtc_bwd(res, g):
 _grad_to_compute_dtype.defvjp(_gtc_fwd, _gtc_bwd)
 
 
-@jax.custom_vjp
-def _barrier(x):
-    """``optimization_barrier`` with a defined VJP (identity-with-barrier on
-    both passes).  The primitive itself has no differentiation rule, so the
-    bare ``jax.lax.optimization_barrier`` call aborts any ``grad`` through
-    the layer scan; semantically the barrier IS the identity, and the
-    backward barrier keeps XLA from hoisting the cotangent upcast out of
-    the backward scan for the same reason as the forward one."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier(x), None
-
-
-def _barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
 def _dtype(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
 
@@ -186,8 +164,9 @@ def _layer_apply(cfg: ModelConfig, kind: str, is_moe: bool, lp: Dict,
     aux = jnp.zeros((), jnp.float32)
     # barrier: stops XLA hoisting the per-layer bf16->f32 norm upcast out of
     # the scan loop (which would materialize an f32 copy of the entire
-    # (L, B, S, d) carry stack — observed on XLA:CPU)
-    x = _barrier(x)
+    # (L, B, S, d) carry stack — observed on XLA:CPU); its transpose puts
+    # the same barrier on the cotangent in the backward scan
+    x = jax.lax.optimization_barrier(x)
     # Megatron-SP discipline (training): the residual is sequence-sharded
     # between layers; gather the *activations* (tokens x d, small at
     # microbatched train shapes) at layer entry so the TP matmuls never

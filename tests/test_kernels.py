@@ -1,5 +1,8 @@
 """Pallas kernels vs pure-jnp oracles — shape/dtype sweeps in
-interpret=True mode (the CPU validation contract)."""
+interpret=True mode (the CPU validation contract).  The kernels default to
+compiled mode, so every call here asks for the interpreter explicitly."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,15 +17,16 @@ from repro.kernels import ops, ref
 def test_maxplus_matmul(m, k, n, rng):
     A = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
     B = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-    out = ops.maxplus_matmul(A, B, bm=32, bk=32, bn=32)
+    out = ops.maxplus_matmul(A, B, bm=32, bk=32, bn=32, interpret=True)
     np.testing.assert_allclose(out, ref.maxplus_matmul_ref(A, B), atol=1e-5)
 
 
 def test_maxplus_associativity(rng):
     A, B, C = (jnp.asarray(rng.normal(size=(16, 16)), jnp.float32)
                for _ in range(3))
-    left = ops.maxplus_matmul(ops.maxplus_matmul(A, B), C)
-    right = ops.maxplus_matmul(A, ops.maxplus_matmul(B, C))
+    mm = functools.partial(ops.maxplus_matmul, interpret=True)
+    left = mm(mm(A, B), C)
+    right = mm(A, mm(B, C))
     np.testing.assert_allclose(left, right, atol=1e-4)
 
 
@@ -31,7 +35,7 @@ def test_maxplus_matvec(rng):
     from repro.kernels.maxplus import maxplus_matvec_pallas
     A = jnp.asarray(rng.normal(size=(64, 64)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(64,)), jnp.float32)
-    out = maxplus_matvec_pallas(A, v)
+    out = maxplus_matvec_pallas(A, v, interpret=True)
     want = jnp.max(A + v[None, :], axis=1)
     np.testing.assert_allclose(out, want, atol=1e-5)
 
@@ -46,7 +50,8 @@ def test_maxplus_matvec(rng):
 def test_systolic_gemm(m, k, n, dt, act, rng):
     A = jnp.asarray(rng.normal(size=(m, k)), dt)
     B = jnp.asarray(rng.normal(size=(k, n)), dt)
-    out = ops.gemm(A, B, activation=act, bm=32, bk=64, bn=32)
+    out = ops.gemm(A, B, activation=act, bm=32, bk=64, bn=32,
+                   interpret=True)
     want = ref.gemm_ref(A, B, activation=act)
     atol = 2e-2 if dt == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(out, want, atol=atol, rtol=1e-2)
@@ -64,7 +69,7 @@ def test_flash_attention(b, h, s, d, causal, window, rng):
     q, k, v = (jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
                for _ in range(3))
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              bq=64, bk=64)
+                              bq=64, bk=64, interpret=True)
     # windowed reference
     s_ = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
     qp = jnp.arange(s)[:, None]
@@ -82,7 +87,8 @@ def test_flash_attention(b, h, s, d, causal, window, rng):
 def test_flash_attention_bf16(rng):
     q, k, v = (jnp.asarray(rng.normal(size=(2, 128, 64)), jnp.bfloat16)
                for _ in range(3))
-    out = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64)
+    out = ops.flash_attention(q, k, v, causal=True, bq=64, bk=64,
+                              interpret=True)
     want = ref.flash_attention_ref(q[:, None].transpose(0, 1, 2, 3).reshape(2, 1, 128, 64),
                                    k.reshape(2, 1, 128, 64),
                                    v.reshape(2, 1, 128, 64), causal=True)
@@ -101,6 +107,15 @@ def test_selective_scan(B, S, D, N, bd, rng):
     c = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
     a = -jnp.asarray(np.abs(rng.normal(size=(D, N))) + 0.1, jnp.float32)
     d = jnp.asarray(rng.normal(size=(D,)), jnp.float32)
-    got = ops.selective_scan(x, dt, b, c, a, d, bd=bd)
+    got = ops.selective_scan(x, dt, b, c, a, d, bd=bd, interpret=True)
     want = ref.selective_scan_ref(x, dt, b, c, a, d)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+def test_flash_attention_noncausal_ragged_raises(rng):
+    """Padded keys cannot be masked without the causal mask: the wrapper
+    refuses instead of quietly running the jnp reference."""
+    q = jnp.asarray(rng.normal(size=(1, 2, 160, 64)), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of bk"):
+        ops.flash_attention(q, q, q, causal=False, bq=64, bk=64,
+                            interpret=True)

@@ -33,7 +33,7 @@ def test_maxplus_matches_ref_any_shape(m, k, n, seed):
     rng = np.random.default_rng(seed)
     A = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
     B = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-    out = ops.maxplus_matmul(A, B, bm=8, bk=8, bn=8)
+    out = ops.maxplus_matmul(A, B, bm=8, bk=8, bn=8, interpret=True)
     np.testing.assert_allclose(out, ref.maxplus_matmul_ref(A, B), atol=1e-5)
 
 
@@ -45,7 +45,7 @@ def test_gemm_kernel_matches_ref_any_shape(mq, kq, nq, seed):
     rng = np.random.default_rng(seed)
     A = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
     B = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-    out = ops.gemm(A, B, bm=16, bk=16, bn=16)
+    out = ops.gemm(A, B, bm=16, bk=16, bn=16, interpret=True)
     np.testing.assert_allclose(out, ref.gemm_ref(A, B), atol=1e-4, rtol=1e-4)
 
 
