@@ -27,6 +27,7 @@ projected-Adam design-space optimizer.
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...tracing import span
 from .builder import (AIDG, CompiledAIDG, CondensedAIDG, compile_aidg,
                       condense_aidg, longest_path_fixed_point)
 from .maxplus import (DEFAULT_ENGINE, NEG, condensed_scan, fixed_point_jax,
@@ -536,6 +538,13 @@ def _sum_last(x: jnp.ndarray) -> jnp.ndarray:
     return x[..., 0]
 
 
+# an instruction of the compiled evaluator's HLO text and the packed scope
+# its ``op_name`` names (``_matrix_fn``'s ``jax.named_scope``s)
+_SCOPED_OP = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([^\s=]+) = [^\n]*?op_name="[^"\n]*?'
+    r'\b(packed\.(?:bucket\d+|compose))\b', re.M)
+
+
 class PackedMatrix:
     """The whole scenario/network matrix as ONE traced evaluator.
 
@@ -570,6 +579,7 @@ class PackedMatrix:
         self._arrays = None           # lazily-built jnp constant pytree
         self._buckets: Optional[List[List[int]]] = None
         self._compiled: Dict[Tuple, Callable] = {}
+        self._last_rows: Optional[int] = None   # last unsharded batch
 
     # -- construction -------------------------------------------------------
 
@@ -608,25 +618,88 @@ class PackedMatrix:
         """Matrix cells composed from the packed rows."""
         return len(self.specs)
 
-    def stats(self) -> Dict[str, float]:
+    def stats(self) -> Dict[str, object]:
         """Aggregate packing/condensation statistics (for benchmarks and
         docs): total vs kept nodes, original vs condensed level totals,
         shape-bucket count, and the padded sequential scan total (one scan
-        per bucket, all in one dispatch)."""
+        per bucket, all in one dispatch).
+
+        ``bucket_detail`` describes each shape bucket in ``_bucketize``
+        order, the order of the ``packed.bucketNN`` named scopes in the
+        compiled evaluator: its row count, padded ``LV`` (scan steps), ``W``
+        and ``P``, its sequential queue steps per dispatch, the matrix
+        columns (cells) whose rows it holds, and the cost model's summed
+        per-row cost ``rcost`` and padded cost ``bcost``.
+        ``pad_efficiency`` is Σ ``rcost`` / Σ ``bcost`` over all buckets:
+        the share of the padded work that is real (1.0 without padding).
+        ``op_scopes`` is :meth:`op_scopes`."""
         conds = [r.cond for r in self.rows]
         lv0 = sum(c.stats["levels"] for c in conds)
         lv1 = sum(c.stats["levels_condensed"] for c in conds)
         buckets = self._bucketize()
-        scan = sum(max(conds[i].schedule.n_levels for i in b)
-                   for b in buckets)
+        cells_of = {}
+        for ci, ids in enumerate(self.row_of):
+            for rid in ids:
+                cells_of.setdefault(rid, set()).add(ci)
+        detail = []
+        for b in buckets:
+            lv, w, p, q = self._bucket_dims(b)
+            detail.append({
+                "rows": len(b), "LV": lv, "W": w, "P": p, "queue_steps": q,
+                "cells": sorted(set().union(*(cells_of[i] for i in b))),
+                "rcost": sum(self._row_cost(i) for i in b),
+                "bcost": self._bucket_cost(b)})
         return {"rows": self.n_rows, "cells": self.n_cells,
                 "nodes": sum(c.n for c in conds),
                 "kept": sum(c.n_kept for c in conds),
                 "levels": lv0, "levels_condensed": lv1,
                 "level_reduction": lv0 / max(1, lv1),
-                "buckets": len(buckets), "scan_len": scan}
+                "buckets": len(buckets),
+                "scan_len": sum(d["LV"] for d in detail),
+                "bucket_detail": detail,
+                "pad_efficiency": (sum(d["rcost"] for d in detail)
+                                   / sum(d["bcost"] for d in detail)),
+                "op_scopes": self.op_scopes()}
+
+    def op_scopes(self) -> Dict[str, str]:
+        """The named scope (``packed.bucketNN`` or ``packed.compose``) of
+        each instruction of the compiled evaluator at the batch size of
+        the latest unsharded dispatch, keyed by HLO instruction name: the
+        name a TPU trace gives each device operation, so a trace's device
+        time can be summed per bucket.  Empty before the first dispatch;
+        after it the lowering and compile are cached, so this costs one
+        pass over the module's text."""
+        if self._last_rows is None:
+            return {}
+        x = jnp.zeros((self._last_rows, self.n_knobs), jnp.float32)
+        text = self._full_fn().lower(x).compile().as_text()
+        return dict(_SCOPED_OP.findall(text))
 
     # -- packed constant arrays --------------------------------------------
+
+    def _queue_len(self, i: int) -> int:
+        """Row ``i``'s sequential multi-slot queue steps per iteration."""
+        return max((len(nd) for nd, _, _, sl, _ in self.rows[i].queues
+                    if sl > 1), default=0)
+
+    def _bucket_dims(self, members: List[int]) -> Tuple[int, int, int, int]:
+        """A bucket's padded (levels, width, preds) maxima and its
+        sequential queue steps per dispatch (``n_iters`` rounds)."""
+        conds = [self.rows[i].cond for i in members]
+        return (max(c.schedule.n_levels for c in conds),
+                max(c.schedule.width for c in conds),
+                max(c.preds_lv.shape[1] for c in conds),
+                self.n_iters * max(self._queue_len(i) for i in members))
+
+    def _row_cost(self, i: int) -> int:
+        """Cost model: row ``i``'s own work, unpadded."""
+        return self._bucket_cost([i])
+
+    def _bucket_cost(self, members: List[int]) -> int:
+        """Cost model: a bucket's work with every member padded to the
+        bucket's maxima."""
+        lv, w, p, q = self._bucket_dims(members)
+        return len(members) * (max(1, lv) * max(1, w) * max(1, p) + q * 8)
 
     def _bucketize(self) -> List[List[int]]:
         """Group rows into shape buckets so padding waste stays bounded:
@@ -641,24 +714,7 @@ class PackedMatrix:
         if self._buckets is not None:
             return self._buckets
         rows = self.rows
-
-        def qlen(i):   # sequential multi-slot queue steps (per iteration)
-            return max((len(nd) for nd, _, _, sl, _ in rows[i].queues
-                        if sl > 1), default=0)
-
-        def rcost(i):
-            c = rows[i].cond
-            return (max(1, c.schedule.n_levels) * max(1, c.schedule.width)
-                    * max(1, c.preds_lv.shape[1])
-                    + self.n_iters * qlen(i) * 8)
-
-        def bcost(members):
-            lv = max(rows[i].cond.schedule.n_levels for i in members)
-            w = max(rows[i].cond.schedule.width for i in members)
-            p = max(rows[i].cond.preds_lv.shape[1] for i in members)
-            q = self.n_iters * max(qlen(i) for i in members)
-            return (len(members)
-                    * (max(1, lv) * max(1, w) * max(1, p) + q * 8))
+        rcost, bcost = self._row_cost, self._bucket_cost
 
         order = sorted(range(len(rows)), key=lambda i: (-rcost(i), i))
         buckets: List[List[int]] = []
@@ -988,27 +1044,31 @@ class PackedMatrix:
             kn = jnp.concatenate([knobs.astype(jnp.float32),
                                   jnp.ones((1,), jnp.float32)])
             ms, ps = [], []
-            for row_fn, row_args in per_bucket:
-                m_b, p_b = jax.vmap(row_fn, in_axes=(0, None, None))(
-                    row_args, kn, tau)
+            # one named scope per bucket (``stats()["bucket_detail"]``
+            # order), so a device trace attributes each scan to its bucket
+            for i, (row_fn, row_args) in enumerate(per_bucket):
+                with jax.named_scope(f"packed.bucket{i:02d}"):
+                    m_b, p_b = jax.vmap(row_fn, in_axes=(0, None, None))(
+                        row_args, kn, tau)
                 ms.append(m_b)
                 ps.append(p_b)
-            m = jnp.concatenate(ms)[inv]
-            p = jnp.concatenate(ps)[inv]
-            mr, pr = m[runs], p[runs]
-            clip = ((lambda a, b: -softmaximum(-a, -b, tau)) if soft
-                    else jnp.minimum)
-            total = _sum_last(reps * mr)
-            within = _sum_last((reps - 1.0) * clip(pr, mr) * fw)
-            if RU > 1:
-                between = _sum_last(clip(pr[:, 1:], mr[:, :-1]) * fb)
-            else:
-                between = 0.0
-            cycles = total - within - between
-            # DVFS-style dynamic term (faster units burn more pJ per op)
-            # plus leakage over the makespan — analytic in θ, and the
-            # static part differentiates through the soft makespan
-            energy = _sum_last(edyn * (1.0 / kn)) + pstat * cycles
+            with jax.named_scope("packed.compose"):
+                m = jnp.concatenate(ms)[inv]
+                p = jnp.concatenate(ps)[inv]
+                mr, pr = m[runs], p[runs]
+                clip = ((lambda a, b: -softmaximum(-a, -b, tau)) if soft
+                        else jnp.minimum)
+                total = _sum_last(reps * mr)
+                within = _sum_last((reps - 1.0) * clip(pr, mr) * fw)
+                if RU > 1:
+                    between = _sum_last(clip(pr[:, 1:], mr[:, :-1]) * fb)
+                else:
+                    between = 0.0
+                cycles = total - within - between
+                # DVFS-style dynamic term (faster units burn more pJ per
+                # op) plus leakage over the makespan — analytic in θ, and
+                # the static part differentiates through the soft makespan
+                energy = _sum_last(edyn * (1.0 / kn)) + pstat * cycles
             return cycles, energy
 
         return fn
@@ -1101,17 +1161,24 @@ class PackedMatrix:
         else:
             mult = 1
             fn = self._full_fn()
-        kt = jnp.asarray(np.atleast_2d(np.asarray(knob_thetas, np.float32)))
+        kt = np.atleast_2d(np.asarray(knob_thetas, np.float32))
         B = kt.shape[0]
 
         def run(block, rows):
-            """Evaluate ``block`` padded with θ = 1 rows up to ``rows``."""
+            """Evaluate ``block`` padded with θ = 1 rows up to ``rows``:
+            upload and launch, wait for the device, then fetch."""
             n = block.shape[0]
-            if n < rows:
-                block = jnp.concatenate(
-                    [block, jnp.ones((rows - n, kt.shape[1]), jnp.float32)])
-            c, en = fn(block)
-            return np.asarray(c)[:n], np.asarray(en)[:n]
+            if not sharded:
+                self._last_rows = rows
+            with span("packed.dispatch"):
+                if n < rows:
+                    block = np.concatenate(
+                        [block, np.ones((rows - n, kt.shape[1]), np.float32)])
+                out = fn(jnp.asarray(block))
+            with span("packed.wait"):
+                c, en = jax.block_until_ready(out)
+            with span("packed.fetch"):
+                return np.asarray(c)[:n], np.asarray(en)[:n]
 
         up = lambda n: -(-n // mult) * mult   # round up to device multiple
         if chunk is None or B <= chunk:

@@ -56,6 +56,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ...tracing import span
 from ..acadl.sim import build_trace, simulate
 from ..archs.energy import energy_model
 from .builder import (AIDG, CompiledAIDG, LevelSchedule, build_aidg,
@@ -665,7 +666,8 @@ class Explorer:
         # and energy are exactly 1.0 per scenario — CompiledScenario
         # .baseline comes from the numpy fixed-point pass, whose iteration
         # count/early-stop can differ by a fraction of a cycle
-        bl, ebl = self.evaluate_full(np.ones((1, space.n), np.float32))
+        with span("explore.baselines"):
+            bl, ebl = self.evaluate_full(np.ones((1, space.n), np.float32))
         self._baselines = bl[0]
         self._energy_baselines = np.maximum(ebl[0], 1e-30)
 
@@ -797,17 +799,25 @@ class Explorer:
     def explore(self, knob_thetas: np.ndarray,
                 chunk: Optional[int] = None) -> ExplorationResult:
         """Evaluate + score + Pareto-extract one candidate batch (three
-        objectives: latency, energy, area cost)."""
-        kt = np.asarray(knob_thetas, np.float32)
-        if kt.ndim == 1:
-            kt = kt[None, :]
-        cycles, energy_pj = self.evaluate_full(kt, chunk=chunk)
-        latency = (cycles / self.baselines[None, :]).mean(axis=1)
-        energy = (energy_pj / self.energy_baselines[None, :]).mean(axis=1)
-        cost = self.cost_proxy(kt)
-        front = pareto_front(np.stack([latency, energy, cost], axis=1))
-        return ExplorationResult(self.space, self.scenario_names, kt, cycles,
-                                 latency, energy, cost, front)
+        objectives: latency, energy, area cost).  Each step is a host span
+        (``repro.tracing``) under ``explore.call``: ``explore.evaluate``,
+        ``explore.score`` and ``explore.pareto``."""
+        with span("explore.call"):
+            kt = np.asarray(knob_thetas, np.float32)
+            if kt.ndim == 1:
+                kt = kt[None, :]
+            with span("explore.evaluate"):
+                cycles, energy_pj = self.evaluate_full(kt, chunk=chunk)
+            with span("explore.score"):
+                latency = (cycles / self.baselines[None, :]).mean(axis=1)
+                energy = (energy_pj
+                          / self.energy_baselines[None, :]).mean(axis=1)
+                cost = self.cost_proxy(kt)
+            with span("explore.pareto"):
+                front = pareto_front(np.stack([latency, energy, cost],
+                                              axis=1))
+            return ExplorationResult(self.space, self.scenario_names, kt,
+                                     cycles, latency, energy, cost, front)
 
     # -- refinement: coordinate descent or gradient descent -----------------
 

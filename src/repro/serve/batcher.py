@@ -30,9 +30,13 @@ import time
 import weakref
 from concurrent.futures import Future
 from contextlib import contextmanager
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-__all__ = ["MicroBatcher", "plan_batches"]
+__all__ = ["LOG_CAP", "MicroBatcher", "append_capped", "plan_batches"]
+
+# dispatch and window logs keep their most recent entries only: a service
+# lives for days, and running counters carry the totals
+LOG_CAP = 1024
 
 # every live batcher, so interpreter shutdown can flush + join the worker
 # threads of instances nobody explicitly closed (weak: a collected batcher
@@ -49,6 +53,14 @@ def _close_all() -> None:
 
 
 atexit.register(_close_all)
+
+
+def append_capped(log: List, entry) -> None:
+    """Append ``entry`` to ``log``, dropping the oldest entries beyond
+    :data:`LOG_CAP`."""
+    log.append(entry)
+    if len(log) > LOG_CAP:
+        del log[: len(log) - LOG_CAP]
 
 
 def plan_batches(n: int, max_batch: int) -> List[Tuple[int, int]]:
@@ -73,8 +85,12 @@ class MicroBatcher:
     ``SystemExit``, injected ``WorkerKill``) additionally re-raises after
     failing the futures, so the worker dies instead of swallowing it; the
     forwarded exception carries the window's items as ``batch_items``.
-    ``dispatch_log`` records the sequence numbers of every batch, in
-    dispatch order — the partition evidence tests assert on."""
+    ``dispatch_log`` records the sequence numbers of each batch, in
+    dispatch order — the partition evidence tests assert on — for the
+    most recent :data:`LOG_CAP` batches.  ``queue_wait_s``,
+    ``queue_waited`` and ``queue_wait_max_s`` total, count and bound each
+    dispatched item's wait from its ``submit`` to the start of its
+    window's dispatch."""
 
     def __init__(self, dispatch: Callable[[List], List],
                  max_batch: int = 8, window_s: float = 0.002):
@@ -87,13 +103,17 @@ class MicroBatcher:
         self.window_s = float(window_s)
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
+        # (seq, item, future, deadline, submit time)
         self._pending: List[Tuple[int, object, Future,
-                                  Optional[float]]] = []
+                                  Optional[float], float]] = []
         self._seq = 0
         self._held = 0
         self._in_flight = 0
         self._closed = False
         self.dispatch_log: List[List[int]] = []
+        self.queue_wait_s = 0.0         # submit -> dispatch start, summed
+        self.queue_waited = 0           # dispatched items behind that sum
+        self.queue_wait_max_s = 0.0
         self.cancelled = 0              # futures cancelled before dispatch
         self.worker_restarts = 0        # respawns after a worker death
         self._dead = False              # worker announced its own death
@@ -133,10 +153,19 @@ class MicroBatcher:
             if self._closed:
                 raise RuntimeError("MicroBatcher is closed")
             self._ensure_worker()
-            self._pending.append((self._seq, item, fut, deadline))
+            self._pending.append((self._seq, item, fut, deadline,
+                                  time.monotonic()))
             self._seq += 1
             self._cond.notify_all()
         return fut
+
+    def queue_wait(self) -> Dict[str, float]:
+        """``queue_wait_s``, ``queue_waited`` and ``queue_wait_max_s``,
+        read together."""
+        with self._cond:
+            return {"queue_wait_s": self.queue_wait_s,
+                    "queue_waited": self.queue_waited,
+                    "queue_wait_max_s": self.queue_wait_max_s}
 
     @contextmanager
     def hold(self):
@@ -194,7 +223,7 @@ class MicroBatcher:
     # -- worker side --------------------------------------------------------
 
     def _take_batch(self) -> List[Tuple[int, object, Future,
-                                        Optional[float]]]:
+                                        Optional[float], float]]:
         """Wait for a window to close, then pop the next FIFO batch: at
         most ``max_batch`` items, no earlier than ``window_s`` after the
         window's first item arrived — or the earliest per-item deadline
@@ -215,7 +244,8 @@ class MicroBatcher:
                 # still flush or the worker (and its joiner) deadlocks
                 if self._pending and (not self._held or self._closed):
                     deadline = self._window_open + self.window_s
-                    for _, _, _, item_dl in self._pending[: self.max_batch]:
+                    for _, _, _, item_dl, _ in self._pending[
+                            : self.max_batch]:
                         if item_dl is not None:
                             deadline = min(deadline, item_dl)
                     if (len(self._pending) >= self.max_batch
@@ -256,8 +286,13 @@ class MicroBatcher:
             if not batch:
                 return
             with self._cond:
-                self._window_open = time.monotonic()
-            items = [it for _, it, _, _ in batch]
+                now = self._window_open = time.monotonic()
+                for *_, t_submit in batch:
+                    self.queue_wait_s += now - t_submit
+                    self.queue_wait_max_s = max(self.queue_wait_max_s,
+                                                now - t_submit)
+                self.queue_waited += len(batch)
+            items = [it for _, it, *_ in batch]
             try:
                 results = self._dispatch(items)
                 if len(results) != len(items):
@@ -271,8 +306,8 @@ class MicroBatcher:
                     e.batch_items = tuple(items)
                 except Exception:       # __slots__ exceptions: best-effort
                     pass
-                self.dispatch_log.append([seq for seq, *_ in batch])
-                for _, _, fut, _ in batch:
+                append_capped(self.dispatch_log, [seq for seq, *_ in batch])
+                for _, _, fut, *_ in batch:
                     self._resolve(fut, e)
                 self._settle()
                 if not isinstance(e, Exception):
@@ -292,8 +327,8 @@ class MicroBatcher:
                             self._worker = self._spawn_worker()
                     raise
                 continue
-            self.dispatch_log.append([seq for seq, *_ in batch])
-            for (_, _, fut, _), res in zip(batch, results):
+            append_capped(self.dispatch_log, [seq for seq, *_ in batch])
+            for (_, _, fut, *_), res in zip(batch, results):
                 self._resolve(fut, res)
             self._settle()
 

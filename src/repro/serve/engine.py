@@ -42,7 +42,8 @@ import numpy as np
 
 from ..core.aidg.explorer import (Explorer, pareto_front, random_candidates,
                                   resolve_cells, scenario_cache_stats)
-from .batcher import MicroBatcher, plan_batches
+from ..tracing import span, spanned
+from .batcher import MicroBatcher, append_capped, plan_batches
 from .errors import (DeadlineExceeded, OracleUnavailable, PoisonedDispatch,
                      TransientDispatchError)
 from .faults import ENV_FAULT_PLAN, FaultInjector, FaultPlan, WorkerKill
@@ -157,8 +158,12 @@ class DSEService:
         self.timeouts = 0               # query() timeouts (leak-accounted)
         self.deadline_misses = 0        # submissions expired pre-evaluation
         self.retries = 0                # packed attempts beyond the first
-        # every window that reached _dispatch (threaded OR replay), as
-        # query keys; and the deduped keys each DEVICE dispatch evaluated
+        self.windows = 0                # windows that reached _dispatch
+        self.dispatched_queries = 0     # submissions in those windows
+        self.device_dispatches = 0      # exact-tier device dispatches
+        # the most recent windows that reached _dispatch (threaded OR
+        # replay), as query keys; and the deduped keys each recent DEVICE
+        # dispatch evaluated (``batcher.LOG_CAP`` entries each)
         self.window_log: List[List[Tuple]] = []
         self.evaluated_log: List[List[Tuple]] = []
         self.batcher = MicroBatcher(self._dispatch, max_batch=max_batch,
@@ -283,13 +288,16 @@ class DSEService:
         surrogate is armed) — plus the failure-semantics counters: the
         circuit ``breaker`` snapshot, ``retries``, ``timeouts``,
         ``deadline_misses``, and the batcher's ``cancelled`` /
-        ``worker_restarts``."""
+        ``worker_restarts`` — and the micro-batch queue wait of every
+        query the batcher dispatched, from its submit to the start of its
+        window's dispatch: summed (``queue_wait_s``), counted
+        (``queue_waited``) and its largest (``queue_wait_max_s``)."""
         with self._lock:
             cs = dict(self.cache_stats)
             cand = self.dispatched_candidates
-            windows = len(self.window_log)
-            n_queries = sum(len(b) for b in self.window_log)
-            device = len(self.evaluated_log)
+            windows = self.windows
+            n_queries = self.dispatched_queries
+            device = self.device_dispatches
             tiers = dict(self.tier_counts)
             tier_time = dict(self.tier_time_s)
             timeouts = self.timeouts
@@ -327,6 +335,7 @@ class DSEService:
             "deadline_misses": deadline_misses,
             "cancelled": self.batcher.cancelled,
             "worker_restarts": self.batcher.worker_restarts,
+            **self.batcher.queue_wait(),
             "fault_plan": (self.fault_plan.to_spec()
                            if self.fault_plan is not None else None),
         }
@@ -399,47 +408,52 @@ class DSEService:
         """
         subs = [s if isinstance(s, _Submission) else _Submission(s)
                 for s in submissions]
-        now = time.monotonic()
         with self._lock:
-            outcomes: List[Optional[object]] = [None] * len(subs)
-            answers: Dict[Tuple, object] = {}
-            fresh: Dict[Tuple, Query] = {}
-            self.window_log.append([s.query.key for s in subs])
-            for i, sub in enumerate(subs):
-                q = sub.query
-                if sub.deadline is not None and now > sub.deadline:
-                    self.deadline_misses += 1
-                    outcomes[i] = DeadlineExceeded(
-                        f"query expired {now - sub.deadline:.3f}s before "
-                        f"evaluation", workload=q.workload)
-                elif q.key in answers or q.key in fresh:
-                    self.cache_stats["coalesced"] += 1
-                elif q.key in self._cache:
-                    self.cache_stats["hits"] += 1
-                    cached = self._cache[q.key]
-                    answers[q.key] = Answer(cached.query, cached.cells,
-                                            cached.designs,
-                                            cached.best_arch, cached=True,
-                                            tier=cached.tier,
-                                            err_bound=cached.err_bound)
-                else:
-                    self.cache_stats["misses"] += 1
-                    fresh[q.key] = q
+            seq = self.windows
+            self.windows += 1
+            self.dispatched_queries += len(subs)
+            append_capped(self.window_log, [s.query.key for s in subs])
+        with span("serve.window", window=seq):
+            now = time.monotonic()
+            with self._lock:
+                outcomes: List[Optional[object]] = [None] * len(subs)
+                answers: Dict[Tuple, object] = {}
+                fresh: Dict[Tuple, Query] = {}
+                for i, sub in enumerate(subs):
+                    q = sub.query
+                    if sub.deadline is not None and now > sub.deadline:
+                        self.deadline_misses += 1
+                        outcomes[i] = DeadlineExceeded(
+                            f"query expired {now - sub.deadline:.3f}s before "
+                            f"evaluation", workload=q.workload)
+                    elif q.key in answers or q.key in fresh:
+                        self.cache_stats["coalesced"] += 1
+                    elif q.key in self._cache:
+                        self.cache_stats["hits"] += 1
+                        cached = self._cache[q.key]
+                        answers[q.key] = Answer(cached.query, cached.cells,
+                                                cached.designs,
+                                                cached.best_arch, cached=True,
+                                                tier=cached.tier,
+                                                err_bound=cached.err_bound)
+                    else:
+                        self.cache_stats["misses"] += 1
+                        fresh[q.key] = q
 
-        if fresh:
-            # staged oracle hierarchy: queries whose every resolved cell
-            # clears the surrogate's calibrated bound answer from the fast
-            # tier; the rest fall back to the exact packed dispatch
-            sur = {k: q for k, q in fresh.items()
-                   if self._surrogate_answers(q)}
-            packed = {k: q for k, q in fresh.items() if k not in sur}
-            if sur:
-                self._answer_surrogate(sur, answers)
-            if packed:
-                self._answer_packed(packed, answers)
+            if fresh:
+                # staged oracle hierarchy: queries whose every resolved cell
+                # clears the surrogate's calibrated bound answer from the fast
+                # tier; the rest fall back to the exact packed dispatch
+                sur = {k: q for k, q in fresh.items()
+                       if self._surrogate_answers(q)}
+                packed = {k: q for k, q in fresh.items() if k not in sur}
+                if sur:
+                    self._answer_surrogate(sur, answers)
+                if packed:
+                    self._answer_packed(packed, answers)
 
-        return [o if o is not None else answers[s.query.key]
-                for o, s in zip(outcomes, subs)]
+            return [o if o is not None else answers[s.query.key]
+                    for o, s in zip(outcomes, subs)]
 
     def _surrogate_answers(self, q: Query) -> bool:
         """True when the armed surrogate's calibrated per-cell bounds
@@ -456,6 +470,7 @@ class DSEService:
             self._sur_ok[key] = ok
         return ok
 
+    @spanned("serve.surrogate")
     def _answer_surrogate(self, group: Dict[Tuple, Query],
                           answers: Dict[Tuple, object],
                           degraded: bool = False) -> None:
@@ -488,6 +503,7 @@ class DSEService:
             self.tier_counts[tier] += len(group)
             self.tier_time_s[tier] += time.perf_counter() - t0
 
+    @spanned("serve.exact_tier")
     def _answer_packed(self, group: Dict[Tuple, Query],
                        answers: Dict[Tuple, object]) -> None:
         """Exact tier: one candidate block per distinct override
@@ -529,7 +545,8 @@ class DSEService:
             [0] + [blocks[s].shape[0] for s in sigs[:-1]])))
         with self._lock:
             self.dispatched_candidates += stacked.shape[0]
-            self.evaluated_log.append(list(group))
+            self.device_dispatches += 1
+            append_capped(self.evaluated_log, list(group))
             for key, q in group.items():
                 s = int(starts[q.overrides])
                 block = blocks[q.overrides]
@@ -609,6 +626,7 @@ class DSEService:
         if cover:
             self._answer_surrogate(cover, answers, degraded=True)
 
+    @spanned("serve.rank")
     def _rank(self, q: Query, cand: np.ndarray, cycles: np.ndarray,
               energy_pj: np.ndarray, tier: str = "packed") -> Answer:
         """Score one query's candidate block over its resolved cell subset

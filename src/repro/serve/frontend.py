@@ -41,6 +41,7 @@ import struct
 import threading
 from typing import Dict, Mapping, Optional, Tuple
 
+from ..tracing import spanned
 from .engine import DSEService
 from .errors import (InvalidQuery, Overloaded, ServeError, error_from_payload,
                      error_payload)
@@ -164,6 +165,7 @@ class ServeFrontend:
 
     # -- request handling ---------------------------------------------------
 
+    @spanned("frontend.request")
     def _handle(self, req: Dict) -> Dict:
         op = req.get("op", "query")
         if op == "health":
