@@ -551,8 +551,9 @@ class PackedMatrix:
     Every unique (condensed) per-layer problem across all cells becomes one
     *row*: its level windows, predecessor slots, absorbed-prefix tables,
     and storage queues are padded to shared shapes and evaluated by a
-    ``vmap`` over rows inside a ``vmap`` over candidates — all cells x all
-    candidates in a single jitted dispatch, with masking keeping padded
+    ``vmap`` over rows, each row's scans carrying the candidate batch as
+    their trailing (lane) axis — all cells x all candidates in a single
+    jitted dispatch, with masking keeping padded
     rows/slots/accesses inert.  Rows are grouped into *shape buckets*
     (``_bucketize``) so a width-1 chain cell never pays a wide systolic
     cell's window; every bucket's vmapped scan lives in the same trace, so
@@ -829,6 +830,7 @@ class PackedMatrix:
         return dict(
             NK=NK, W=W, P=P, LV=LV, AB=AB,
             has_chains=any(r.cond.stats["n_coupled"] > 0 for r in rows),
+            has_absorbed=any(r.cond.n_absorbed > 0 for r in rows),
             fu=J(fu), mem=J(mem), base=J(base), opk=J(opk), stk=J(stk),
             nmask=J(nmask), prol=J(prol), has_prol=J(has_prol),
             preds=J(preds), const=J(const), pidx=J(pidx), vc=J(vc), vp=J(vp),
@@ -885,11 +887,17 @@ class PackedMatrix:
                  "ab_fu", "ab_opk", "ab_const", "ab_seg")
 
     def _row_fn(self, A, soft: bool):
-        """One packed row's fixed point: (row-array dict, kn, tau) ->
-        (makespan, prologue completion).  Python-level ``soft`` selects the
-        hard max family or the τ-tempered LSE family at trace time; the
-        queue families' static attributes (slot width, ordered-ness,
-        presence) specialize the trace per bucket."""
+        """One packed row's fixed point for a batch of candidates:
+        (row-array dict, kn (K+1, B), tau) -> (makespan (B,), prologue
+        completion (B,)).  The candidate axis is the trailing (lane) axis
+        of every value array, so each scan step reads and writes whole
+        lane rows: the relaxation's state write is a dense slice and the
+        multi-slot queue's slot update a select, never a per-candidate
+        scatter, and a queue's service order is a sort that carries its
+        data along, never a gather across the lanes.  Python-level ``soft`` selects the hard max family or the
+        τ-tempered LSE family at trace time; the queue families' static
+        attributes (slot width, ordered-ness, presence) specialize the
+        trace per bucket."""
         NK, W = A["NK"], A["W"]
         n_iters = self.n_iters
         qstatic = [(key, g["SL"], key.startswith("s1"), key.endswith("o"))
@@ -899,90 +907,107 @@ class PackedMatrix:
             (fu, mem, base0, opk, stk, nmask, prol, has_prol, preds, const,
              pidx, vc, vp, starts, ab_fu, ab_opk, ab_const, ab_seg) = (
                 args[k] for k in self._ROW_KEYS)
+            B = kn.shape[1]
             if soft:
                 floor = lambda x: softmaximum(jnp.float32(1.0), x, tau)
                 reduce2 = lambda a, b: softmaximum(a, b, tau)
             else:
                 floor = lambda x: jnp.maximum(jnp.float32(1.0), x)
                 reduce2 = jnp.maximum
-            w = floor(fu * kn[opk] + mem * kn[stk])
-            aw = floor(ab_fu * kn[ab_opk]) + ab_const
-            tot0 = jnp.concatenate([jnp.zeros((1,), jnp.float32),
-                                    jnp.cumsum(aw)])
-            prefix = tot0[1:] - tot0[ab_seg]
-            extra = const + jnp.where(pidx >= 0,
-                                      prefix[jnp.maximum(pidx, 0)], 0.0)
-            w_pad = jnp.concatenate([w, jnp.zeros((W,), jnp.float32)])
-            v_lv = jnp.where(
-                vc > NEG / 2,
-                vc + jnp.where(vp >= 0, prefix[jnp.maximum(vp, 0)], 0.0)
-                + w_pad, NEG)
+            w = floor(fu[:, None] * kn[opk] + mem[:, None] * kn[stk])
+            if A["has_absorbed"]:
+                aw = floor(ab_fu[:, None] * kn[ab_opk]) + ab_const[:, None]
+                tot0 = jnp.concatenate([jnp.zeros((1, B), jnp.float32),
+                                        jnp.cumsum(aw, axis=0)])
+                prefix = tot0[1:] - tot0[ab_seg]
+                extra = const[..., None] + jnp.where(
+                    (pidx >= 0)[..., None], prefix[jnp.maximum(pidx, 0)],
+                    0.0)
+                vpre = jnp.where((vp >= 0)[:, None],
+                                 prefix[jnp.maximum(vp, 0)], 0.0)
+            else:
+                # θ reaches the extras only through absorbed prefixes, so
+                # here they keep one lane for the scan to broadcast: a
+                # constant (NK+W, P, B) extra was re-filled at every step
+                extra, vpre = const[..., None], 0.0
+            w_pad = jnp.concatenate([w, jnp.zeros((W, B), jnp.float32)])
+            v_lv = jnp.where((vc > NEG / 2)[:, None],
+                             vc[:, None] + vpre + w_pad, NEG)
 
             def relax(b):
                 return condensed_scan(w, b, extra, v_lv, preds, starts,
                                       tau=tau if soft else None,
                                       has_chains=A["has_chains"])
 
+            def arrivals(nd0, lat0, knob, t, ordered):
+                """A storage's accesses (SA,) as (mask, node, arrival and
+                latency (SA, B) in service order, the order)."""
+                msk = nd0 >= 0
+                nd = jnp.maximum(nd0, 0)
+                lat = lat0[:, None] * kn[knob]
+                arr = jnp.where(msk[:, None], t[nd] - w[nd], _BIG)
+                if ordered:   # provably static order: argsort = id
+                    return msk, nd, arr, lat, None
+                # one stable sort per candidate carries the latencies and
+                # the order along: a gather along the access axis would
+                # fetch across the candidate lanes element by element
+                iota = jax.lax.broadcasted_iota(jnp.int32, arr.shape, 0)
+                arr_s, lat_s, o = jax.lax.sort((arr, lat, iota), dimension=0,
+                                               num_keys=1, is_stable=True)
+                return msk, nd, arr_s, lat_s, o
+
+            def unsort(done_s, o, msk, nd):
+                """Service completions back in access order, as the
+                queue's (node, need) pairs."""
+                if o is not None:
+                    # sort back by the order: on the chip a scatter across
+                    # the candidate lanes cost ten times this sort
+                    _, done_s = jax.lax.sort((o, done_s), dimension=0,
+                                             num_keys=1)
+                need = jnp.where(msk[:, None], done_s + fu[nd][:, None]
+                                 - w[nd], NEG)
+                return jnp.where(msk, nd, NK), need
+
             def q_single(ordered):
                 def q(nd0, lat0, knob, t):
-                    msk = nd0 >= 0
-                    nd = jnp.maximum(nd0, 0)
-                    lat = lat0 * kn[knob]
-                    arr = jnp.where(msk, t[nd] - w[nd], _BIG)
-                    if ordered:   # provably static order: argsort = id
-                        arr_s, lat_s = arr, lat
-                    else:
-                        o = jnp.argsort(arr)
-                        arr_s, lat_s = arr[o], lat[o]
-                    S = jnp.cumsum(lat_s)
+                    msk, nd, arr_s, lat_s, o = arrivals(nd0, lat0, knob, t,
+                                                        ordered)
+                    S = jnp.cumsum(lat_s, axis=0)
                     z = arr_s - S + lat_s
                     if soft:
-                        done_s = S + tau * jax.lax.cumlogsumexp(z / tau)
+                        done_s = S + tau * jax.lax.cumlogsumexp(z / tau,
+                                                                axis=0)
                     else:
-                        done_s = S + jax.lax.cummax(z)
-                    if ordered:
-                        done = done_s
-                    else:   # inverse permutation by scatter, not a 2nd sort
-                        inv = (jnp.zeros_like(o).at[o]
-                               .set(jnp.arange(o.shape[0])))
-                        done = done_s[inv]
-                    need = jnp.where(msk, done + fu[nd] - w[nd], NEG)
-                    return jnp.where(msk, nd, NK), need
+                        done_s = S + jax.lax.cummax(z, axis=0)
+                    return unsort(done_s, o, msk, nd)
                 return q
 
             def q_multi(ordered, SL):
+                slot = jnp.arange(SL)[:, None]
+
                 def q(nd0, lat0, knob, slots, t):
-                    msk = nd0 >= 0
-                    nd = jnp.maximum(nd0, 0)
-                    lat = lat0 * kn[knob]
-                    arr = jnp.where(msk, t[nd] - w[nd], _BIG)
-                    if ordered:
-                        arr_s, lat_s = arr, lat
-                    else:
-                        o = jnp.argsort(arr)
-                        arr_s, lat_s = arr[o], lat[o]
+                    msk, nd, arr_s, lat_s, o = arrivals(nd0, lat0, knob, t,
+                                                        ordered)
 
                     def step(free, inp):
                         a, l = inp
-                        k = jnp.argmin(free)   # earliest-free slot
-                        done = reduce2(a, free[k]) + l
-                        return free.at[k].set(done), done
+                        # earliest-free slot (first on ties), read and
+                        # written by select so the candidates stay on
+                        # the lanes; free[k] is min(free)
+                        hot = slot == jnp.argmin(free, axis=0)
+                        done = reduce2(a, jnp.sum(jnp.where(hot, free, 0.0),
+                                                  axis=0)) + l
+                        return jnp.where(hot, done, free), done
 
-                    free0 = jnp.where(jnp.arange(SL) < slots, 0.0, _BIG)
+                    free0 = jnp.broadcast_to(
+                        jnp.where(slot < slots, 0.0, _BIG), (SL, B))
                     _, done_s = jax.lax.scan(step, free0, (arr_s, lat_s))
-                    if ordered:
-                        done = done_s
-                    else:
-                        inv = (jnp.zeros_like(o).at[o]
-                               .set(jnp.arange(o.shape[0])))
-                        done = done_s[inv]
-                    need = jnp.where(msk, done + fu[nd] - w[nd], NEG)
-                    return jnp.where(msk, nd, NK), need
+                    return unsort(done_s, o, msk, nd)
                 return q
 
-            t = relax(base0)
+            t = relax(jnp.broadcast_to(base0[:, None], (NK, B)))
             for _ in range(n_iters):
-                need_full = jnp.full((NK + 1,), NEG, jnp.float32)
+                need_full = jnp.full((NK + 1, B), NEG, jnp.float32)
                 for key, SL, single, ordered in qstatic:
                     qa = args["queues"][key]
                     if single:
@@ -995,30 +1020,31 @@ class PackedMatrix:
                             in_axes=(0, 0, 0, 0, None))(
                             qa["nd"], qa["lat"], qa["kn"], qa["sl"], t)
                     need_full = need_full.at[nd_g.reshape(-1)].max(
-                        need_g.reshape(-1))
+                        need_g.reshape(-1, B))
                 if soft:
-                    b = softmaximum(base0, need_full[:NK], tau)
+                    b = softmaximum(base0[:, None], need_full[:NK], tau)
                 else:
-                    b = jnp.maximum(base0, need_full[:NK])
+                    b = jnp.maximum(base0[:, None], need_full[:NK])
                 t = relax(b)
 
-            tm = jnp.where(nmask, t, NEG)
-            tp = jnp.where(prol, t, NEG)
+            tm = jnp.where(nmask[:, None], t, NEG)
+            tp = jnp.where(prol[:, None], t, NEG)
             if soft:
-                m = softmax_reduce(tm, tau)
-                p = softmax_reduce(tp, tau)
+                m = softmax_reduce(tm, tau, axis=0)
+                p = softmax_reduce(tp, tau, axis=0)
             else:
-                m = tm.max()
-                p = tp.max()
+                m = tm.max(axis=0)
+                p = tp.max(axis=0)
             return m, jnp.where(has_prol > 0, p, 0.0)
 
         return fn
 
     def _matrix_fn(self, soft: bool):
-        """knobs (K,) [, tau] -> per-cell ``(cycles (S,), energy (S,))``,
-        fully traced: one vmapped wavefront fixed point per shape bucket
-        (all inside the one trace), bucket outputs re-ordered to global
-        rows, then the run-length composition per cell.  The energy
+        """knobs (B, K) [, tau] -> per-cell ``(cycles (B, S), energy (B,
+        S))``, fully traced: one wavefront fixed point per shape bucket,
+        vmapped over the bucket's rows with the candidates on the trailing
+        axis (all inside the one trace), bucket outputs re-ordered to
+        global rows, then the run-length composition per cell.  The energy
         objective rides the SAME trace — the pre-folded dynamic term
         ``Σₖ edynₖ / θₖ`` plus the static term ``P_static · cycles`` — so
         a 3-objective evaluation is still a single dispatch with no second
@@ -1042,33 +1068,35 @@ class PackedMatrix:
 
         def fn(knobs, tau):
             kn = jnp.concatenate([knobs.astype(jnp.float32),
-                                  jnp.ones((1,), jnp.float32)])
+                                  jnp.ones((knobs.shape[0], 1), jnp.float32)],
+                                 axis=1)
             ms, ps = [], []
             # one named scope per bucket (``stats()["bucket_detail"]``
             # order), so a device trace attributes each scan to its bucket
             for i, (row_fn, row_args) in enumerate(per_bucket):
                 with jax.named_scope(f"packed.bucket{i:02d}"):
                     m_b, p_b = jax.vmap(row_fn, in_axes=(0, None, None))(
-                        row_args, kn, tau)
+                        row_args, kn.T, tau)
                 ms.append(m_b)
                 ps.append(p_b)
             with jax.named_scope("packed.compose"):
-                m = jnp.concatenate(ms)[inv]
-                p = jnp.concatenate(ps)[inv]
-                mr, pr = m[runs], p[runs]
+                m = jnp.concatenate(ms)[inv].T
+                p = jnp.concatenate(ps)[inv].T
+                mr, pr = m[:, runs], p[:, runs]
                 clip = ((lambda a, b: -softmaximum(-a, -b, tau)) if soft
                         else jnp.minimum)
                 total = _sum_last(reps * mr)
                 within = _sum_last((reps - 1.0) * clip(pr, mr) * fw)
                 if RU > 1:
-                    between = _sum_last(clip(pr[:, 1:], mr[:, :-1]) * fb)
+                    between = _sum_last(clip(pr[..., 1:], mr[..., :-1]) * fb)
                 else:
                     between = 0.0
                 cycles = total - within - between
                 # DVFS-style dynamic term (faster units burn more pJ per
                 # op) plus leakage over the makespan — analytic in θ, and
                 # the static part differentiates through the soft makespan
-                energy = _sum_last(edyn * (1.0 / kn)) + pstat * cycles
+                energy = (_sum_last(edyn * (1.0 / kn[:, None]))
+                          + pstat * cycles)
             return cycles, energy
 
         return fn
@@ -1076,13 +1104,13 @@ class PackedMatrix:
     # -- public evaluation surface -----------------------------------------
 
     def _full_fn(self) -> Callable:
-        """Cached ``jit(vmap)`` hard evaluator of the FULL objective tuple:
+        """Cached ``jit`` hard evaluator of the FULL objective tuple:
         ``fn(knobs (B, K)) -> ((B, S) cycles, (B, S) energy pJ)`` — the
         whole matrix in one dispatch, energy in the same trace."""
         fn = self._compiled.get("hard")
         if fn is None:
             f = self._matrix_fn(soft=False)
-            fn = jax.jit(jax.vmap(lambda k: f(k, jnp.float32(1.0))))
+            fn = jax.jit(lambda k: f(k, jnp.float32(1.0)))
             self._compiled["hard"] = fn
         return fn
 
@@ -1109,7 +1137,7 @@ class PackedMatrix:
         """Cached device-sharded hard evaluator: ``fn(knobs (B, K)) ->
         ((B, S) cycles, (B, S) energy)`` with the CANDIDATE axis split
         across ``n_shards`` devices via ``jax.shard_map`` — each device
-        runs the same vmapped packed evaluator over its B/D slice, so
+        runs the same batched packed evaluator over its B/D slice, so
         results are bitwise identical to the single-device path
         (per-candidate rows are independent; asserted by
         ``tests/test_serve.py``).  B must be a multiple of the device
@@ -1120,7 +1148,7 @@ class PackedMatrix:
         if fn is None:
             from jax.sharding import Mesh, PartitionSpec as P
             f = self._matrix_fn(soft=False)
-            batched = jax.vmap(lambda k: f(k, jnp.float32(1.0)))
+            batched = lambda k: f(k, jnp.float32(1.0))
             mesh = Mesh(np.asarray(jax.local_devices()[:D]), ("cand",))
             # check_vma=False: the body is the unsharded evaluator, whose
             # scan carries start as replicated constants and come out
@@ -1221,8 +1249,8 @@ class PackedMatrix:
             f = self._matrix_fn(soft=True)
             bl = jnp.asarray(baselines, jnp.float32)
 
-            def val(knobs, tau):
-                return (f(knobs, tau)[0] / bl).mean()
+            def val(knobs, tau):    # one candidate: a lane axis of width 1
+                return (f(knobs[None], tau)[0][0] / bl).mean()
 
             fn = jax.jit(jax.vmap(jax.value_and_grad(val),
                                   in_axes=(0, None)))
@@ -1247,8 +1275,8 @@ class PackedMatrix:
                 np.asarray(energy_baselines, np.float64), 1e-30), jnp.float32)
 
             def vals(knobs, tau):
-                c, en = f(knobs, tau)
-                return jnp.stack([(c / bl).mean(), (en / ebl).mean()])
+                c, en = f(knobs[None], tau)
+                return jnp.stack([(c[0] / bl).mean(), (en[0] / ebl).mean()])
 
             def vg(knobs, tau):
                 return vals(knobs, tau), jax.jacrev(vals)(knobs, tau)

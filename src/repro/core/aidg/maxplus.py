@@ -213,12 +213,22 @@ def condensed_scan(w_perm: jnp.ndarray, b_perm: jnp.ndarray,
     including the target's own work; everything is in the level-major
     permuted layout of ``builder.condense_aidg``.  ``has_chains=False``
     (a trace-time constant) skips the affine scan entirely for graphs
-    with no coupled nodes — the step then reduces to the plain wavefront."""
+    with no coupled nodes — the step then reduces to the plain wavefront.
+
+    The value arrays may carry trailing candidate axes (``w_perm`` (NK,
+    *lanes), ``extra_lv`` (NK+W, P, *lanes), or one lane where the extras
+    are the same for every candidate); the graph arrays ``preds_lv`` and
+    ``starts`` never do.  Each step then reads and writes whole ``lanes``
+    rows, so with one trailing batch axis the candidates sit on the TPU's
+    lanes and every state write is a dense slice."""
     NK = w_perm.shape[0]
+    lanes = w_perm.shape[1:]
     W = preds_lv.shape[0] - NK
     P = preds_lv.shape[1]
-    work_pad = jnp.concatenate([w_perm, jnp.zeros((W,), jnp.float32)])
-    base_pad = jnp.concatenate([b_perm, jnp.full((W,), NEG, jnp.float32)])
+    zeros = (0,) * len(lanes)
+    work_pad = jnp.concatenate([w_perm, jnp.zeros((W,) + lanes, jnp.float32)])
+    base_pad = jnp.concatenate([b_perm,
+                                jnp.full((W,) + lanes, NEG, jnp.float32)])
 
     def op(a, c):
         va, ha = a
@@ -231,11 +241,13 @@ def condensed_scan(w_perm: jnp.ndarray, b_perm: jnp.ndarray,
 
     def step(t, start):
         js = jax.lax.dynamic_slice(preds_lv, (start, 0), (W, P))
-        ex = jax.lax.dynamic_slice(extra_lv, (start, 0), (W, P))
-        wv = jax.lax.dynamic_slice(work_pad, (start,), (W,))
-        bv = jax.lax.dynamic_slice(base_pad, (start,), (W,))
-        vv = jax.lax.dynamic_slice(v_lv, (start,), (W,))
-        vals = jnp.where(js >= 0, t[jnp.maximum(js, 0)] + ex, NEG)
+        ex = jax.lax.dynamic_slice(extra_lv, (start, 0) + zeros,
+                                   (W, P) + extra_lv.shape[2:])
+        wv = jax.lax.dynamic_slice(work_pad, (start,) + zeros, (W,) + lanes)
+        bv = jax.lax.dynamic_slice(base_pad, (start,) + zeros, (W,) + lanes)
+        vv = jax.lax.dynamic_slice(v_lv, (start,) + zeros, (W,) + lanes)
+        live = (js >= 0).reshape((W, P) + (1,) * len(lanes))
+        vals = jnp.where(live, t[jnp.maximum(js, 0)] + ex, NEG)
         # compose the reductions instead of concatenating (LSE composes
         # exactly: lse(b, v₁..v_k) = lse(b, lse(v)) — and the fused
         # gather→where→reduce chain avoids materializing a (W, P+1) buffer)
@@ -247,9 +259,9 @@ def condensed_scan(w_perm: jnp.ndarray, b_perm: jnp.ndarray,
             _, tw = jax.lax.associative_scan(op, (vv, r + wv))
         else:
             tw = r + wv
-        return jax.lax.dynamic_update_slice(t, tw, (start,)), ()
+        return jax.lax.dynamic_update_slice(t, tw, (start,) + zeros), ()
 
-    t0 = jnp.zeros((NK + W,), dtype=jnp.float32)
+    t0 = jnp.zeros((NK + W,) + lanes, dtype=jnp.float32)
     t, _ = jax.lax.scan(step, t0, starts)
     return t[:NK]
 

@@ -167,6 +167,63 @@ def test_packed_theta_one_matches_golden_pins(ex_packed):
             GOLDEN_THETA1_CYCLES[name], abs=0.5), name
 
 
+@pytest.mark.parametrize("width", [1, 300, 1024])
+def test_packed_batch_equals_candidates_one_at_a_time(ex_packed, width):
+    """The candidates ride the evaluator's trailing axis and nothing mixes
+    them: a batch of any width (one, not a multiple of 128, the benchmark's
+    1024) gives bitwise the cycles and energy of each candidate evaluated
+    alone, θ = 1 and seeded random θ alike."""
+    pm = ex_packed.packed_matrix()
+    cand = np.concatenate([
+        np.ones((1, ex_packed.space.n), np.float32),
+        random_candidates(ex_packed.space, width - 1, seed=width,
+                          include_baseline=False)])[:width]
+    cycles, energy = pm.evaluate_full(cand)
+    alone = [pm.evaluate_full(c[None]) for c in cand]
+    assert np.array_equal(cycles, np.concatenate([c for c, _ in alone]))
+    assert np.array_equal(energy, np.concatenate([e for _, e in alone]))
+    assert np.array_equal(cycles[0], ex_packed.baselines)
+
+
+def _mid_run_slot_ties(cell, storage):
+    """Accesses of ``storage`` that, in the first queue pass at θ = 1,
+    find two or more slots free at the same (nonzero) time."""
+    aidg = cell.aidg
+    t = np.asarray(fixed_point_jax(aidg, n_iters=0, engine="condensed"))
+    nd = np.asarray(cell.problem.compiled_aidg.storage_scatter[storage])
+    arrival = t[nd] - aidg.work[nd]
+    order = np.argsort(arrival, kind="stable")
+    free = np.zeros(aidg.storage_slots[storage])
+    ties = 0
+    for a, lat in zip(arrival[order],
+                      np.asarray(aidg.storage_lat[storage])[order]):
+        k = int(np.argmin(free))
+        ties += int(free[k] > 0 and (free == free[k]).sum() > 1)
+        free[k] = max(a, free[k]) + lat
+    return ties
+
+
+def test_packed_multi_slot_queue_ties_match_percell():
+    """At θ = 1 the systolic array's 4-slot DRAM queue finds two slots
+    free at the same time again and again; the packed evaluator's
+    select-based slot update (the first such slot on a tie) gives the
+    per-cell condensed engine's result."""
+    cell = compile_scenario(next(s for s in SCENARIOS
+                                 if s.name == "systolic/gemm"))
+    assert _mid_run_slot_ties(cell, "dram0") > 0
+    ex = Explorer(scenarios=[cell.scenario])
+    kt = np.concatenate([np.ones((1, ex.space.n), np.float32),
+                         random_candidates(ex.space, 3, seed=5,
+                                           include_baseline=False)])
+    packed = ex.evaluate(kt)[:, 0]
+    percell = np.asarray(cell.evaluate(ex.space, kt, ex._projections[0],
+                                       n_iters=ex.n_iters,
+                                       engine="condensed"))
+    assert packed[0] == percell[0]
+    assert np.allclose(packed, percell, rtol=5e-3, atol=0.5), (packed,
+                                                               percell)
+
+
 def test_packed_chunked_evaluate_matches(ex_packed):
     cand = random_candidates(ex_packed.space, 23, seed=9)
     full = ex_packed.evaluate(cand)

@@ -9,6 +9,7 @@ this file loads the TPU library, and a host that cannot describe it
 skips."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +98,67 @@ def test_packed_evaluator_compiles_for_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+# an instruction of compiled HLO text: name, shape dims, layout, opcode
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+) = \w+\[([\d,]*)\]"
+                    r"\{([\d,]*)[^}]*\}\S* ([\w-]+)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.-]+) \(.*\{\s*$")
+_CALLEE = re.compile(r"(?:body|condition|calls|to_apply|branch_computations)"
+                     r"=\{?((?:%?[\w.-]+(?:,\s*)?)+)")
+
+
+def _instructions(text):
+    """``(name, dims, layout, opcode, in_loop)`` of every array instruction
+    of a compiled module, ``in_loop`` when a while body reaches it."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    calls = {c: {n.strip().lstrip("%") for k in _CALLEE.findall("\n".join(ls))
+                 for n in k.split(",")} for c, ls in comps.items()}
+    todo = [b for ls in comps.values() for line in ls
+            for b in re.findall(r"body=%?([\w.-]+)", line)]
+    in_loop = set()
+    while todo:
+        c = todo.pop()
+        if c not in in_loop:
+            in_loop.add(c)
+            todo.extend(calls.get(c, ()))
+    for c, ls in comps.items():
+        for line in ls:
+            m = _INSTR.match(line)
+            if m:
+                name, dims, layout, op = m.groups()
+                yield (name, [int(d) for d in dims.split(",") if d],
+                       [int(d) for d in layout.split(",") if d], op,
+                       c in in_loop)
+
+
+def test_packed_scans_keep_the_candidates_on_the_lanes(one_chip):
+    """The packed evaluator's bucket scans carry the candidate batch as
+    the minor-most (lane) dimension, so every per-step state write is a
+    dense slice or a select.  Two gamma cells make one 2-row bucket with
+    a 2-slot queue, the shape of the ``net28`` benchmark's slowest gamma
+    bucket; with the candidates on a leading axis the relaxation's write
+    touched a partial tile per candidate and the queue's slot update was
+    a per-candidate scatter inside the scan loop."""
+    B = 256
+    cells = [s for s in default_scenarios()
+             if s.name in ("gamma/gemm", "gamma/scan")]
+    ex = Explorer(scenarios=cells)
+    assert ex.packed_matrix().stats()["buckets"] == 1
+    arg = jax.ShapeDtypeStruct((B, ex.space.n), jnp.float32,
+                               sharding=one_chip)
+    text = ex.packed_matrix()._full_fn().lower(arg).compile().as_text()
+    batched = [i for i in _instructions(text) if B in i[1]]
+    writes = [i for i in batched if i[3] == "dynamic-update-slice"]
+    assert any(in_loop for *_, in_loop in writes), "no scan state write"
+    for name, dims, layout, _, _ in writes:
+        assert dims[layout[0]] == B, (name, dims, layout)
+    scattered = [(name, dims) for name, dims, _, op, in_loop in batched
+                 if op == "scatter" and in_loop]
+    assert not scattered, scattered
