@@ -502,6 +502,10 @@ def grid_candidates(space: DesignSpace, points: int = 4) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+# rows ranked per step of pareto_front's block sweep (M >= 3)
+_PARETO_BLOCK = 32
+
+
 def pareto_front(objectives: np.ndarray) -> np.ndarray:
     """Indices of the non-dominated rows of a (B, M >= 2) minimization
     problem, sorted by the first objective.  Deterministic: ties broken by
@@ -513,14 +517,23 @@ def pareto_front(objectives: np.ndarray) -> np.ndarray:
     "non-dominated" purely by having the smallest cost — a diverged sweep
     (θ outside the evaluator's stable range) must not corrupt the frontier.
 
-    The sweep visits rows in lexicographic order (first objective primary),
-    keeping a row unless some earlier sorted row weakly dominates it (<= in
-    every objective) — equivalent to checking kept rows only (<= is
-    transitive: whatever dominates a dominated row also dominates its
-    victims), which turns the scan into one vectorized (B, B) dominance
-    mask instead of a Python pairwise loop (the serving tier ranks every
-    answer through here, so this is a hot path); on 2-objective input it
-    reduces to the classic best-so-far scan bit-for-bit.
+    The sweep visits rows in lexicographic order (first objective primary)
+    and drops a row iff some earlier sorted row weakly dominates it (<= in
+    every objective).  An earlier sorted row is never worse in the first
+    objective, so only the others are compared.  On 2-objective input this
+    is the classic best-so-far scan: a row stays iff its second objective
+    is strictly below every earlier one — O(B).
+
+    With M >= 3 the sorted rows are ranked in blocks of ``_PARETO_BLOCK``.
+    Checking kept rows only is exact (<= is transitive: whatever
+    dominates a dominated row also dominates its victims).  So each block
+    is tested within itself under a lower-triangle mask, and its
+    survivors, which are final, then strike every later row they dominate
+    in one vectorized test.  A block therefore only ever holds rows that no
+    kept row dominates.  The cost is O(B·F + B·b) comparisons for a front
+    of F rows and blocks of b, against the O(B²) of a full (B, B)
+    dominance mask; the result is the same, index for index (the serving
+    tier ranks every answer through here, so this is a hot path).
     """
     objs = np.asarray(objectives, np.float64)
     assert objs.ndim == 2 and objs.shape[1] >= 2
@@ -535,12 +548,24 @@ def pareto_front(objectives: np.ndarray) -> np.ndarray:
     sub = objs[rows]
     m = sub.shape[1]
     order = np.lexsort(tuple(sub[:, j] for j in range(m - 1, -1, -1)))
-    ss = sub[order]
-    # dom[i, j] = sorted row j weakly dominates sorted row i; only j < i
-    # can apply (lexsorted), so mask the upper triangle + diagonal
-    dom = (ss[None, :, :] <= ss[:, None, :]).all(axis=2)
-    dom &= np.tri(len(ss), k=-1, dtype=bool)
-    return np.asarray(rows[order[~dom.any(axis=1)]], dtype=np.int64)
+    rest = sub[order, 1:]           # objective 0 is sorted: never compared
+    if m == 2:
+        best = np.minimum.accumulate(np.concatenate([[np.inf], rest[:-1, 0]]))
+        return np.asarray(rows[order[rest[:, 0] < best]], dtype=np.int64)
+    todo = np.arange(len(rest))     # sorted rows no kept row dominates yet
+    kept = []
+    while todo.size:
+        blk, todo = todo[:_PARETO_BLOCK], todo[_PARETO_BLOCK:]
+        b = rest[blk]
+        dom = (b[None, :, :] <= b[:, None, :]).all(axis=2)
+        dom &= np.tri(len(blk), k=-1, dtype=bool)
+        won = blk[~dom.any(axis=1)]
+        kept.append(won)
+        if todo.size:
+            hit = (rest[won][None, :, :]
+                   <= rest[todo][:, None, :]).all(axis=2).any(axis=1)
+            todo = todo[~hit]
+    return np.asarray(rows[order[np.concatenate(kept)]], dtype=np.int64)
 
 
 def resolve_cells(compiled: Sequence, workload: Optional[str] = None,
@@ -660,6 +685,8 @@ class Explorer:
             else compile_scenario(s, use_cache) for s in cells]
         self._projections = [cs.projection(space) for cs in self.compiled]
         self._weights: Optional[np.ndarray] = None
+        # running totals of explore's ranking, read through stats()
+        self._ranking = {"calls": 0, "rows": 0, "kept": 0}
         self._energy_arrays_cache = None
         # normalization denominators from the SAME evaluator the sweeps use
         # (compiled_sweep at θ = 1), so the baseline candidate's latency
@@ -694,6 +721,15 @@ class Explorer:
         over the per-node scan.  Network cells report their unique-layer
         aggregate."""
         return [cs.stats_row() for cs in self.compiled]
+
+    def stats(self) -> Dict[str, float]:
+        """Running counters of ``explore``'s Pareto ranking: calls, rows
+        ranked and front rows kept, and the mean front size per call (the
+        F that ``pareto_front``'s sweep scales with)."""
+        r = dict(self._ranking)
+        return {"pareto_calls": r["calls"], "pareto_rows": r["rows"],
+                "pareto_kept": r["kept"],
+                "mean_front": r["kept"] / max(1, r["calls"])}
 
     # -- cost/area proxy ----------------------------------------------------
 
@@ -801,7 +837,8 @@ class Explorer:
         """Evaluate + score + Pareto-extract one candidate batch (three
         objectives: latency, energy, area cost).  Each step is a host span
         (``repro.tracing``) under ``explore.call``: ``explore.evaluate``,
-        ``explore.score`` and ``explore.pareto``."""
+        ``explore.score`` and ``explore.pareto`` (whose ``rows`` id is the
+        number of candidates ranked); ``stats()`` totals the ranking."""
         with span("explore.call"):
             kt = np.asarray(knob_thetas, np.float32)
             if kt.ndim == 1:
@@ -813,9 +850,12 @@ class Explorer:
                 energy = (energy_pj
                           / self.energy_baselines[None, :]).mean(axis=1)
                 cost = self.cost_proxy(kt)
-            with span("explore.pareto"):
+            with span("explore.pareto", rows=len(kt)):
                 front = pareto_front(np.stack([latency, energy, cost],
                                               axis=1))
+            self._ranking["calls"] += 1
+            self._ranking["rows"] += len(kt)
+            self._ranking["kept"] += len(front)
             return ExplorationResult(self.space, self.scenario_names, kt,
                                      cycles, latency, energy, cost, front)
 

@@ -157,6 +157,82 @@ def test_pareto_front_ignores_nonfinite_rows():
         assert np.array_equal(pareto_front(clean), base)
 
 
+def _pareto_front_mask(objectives):
+    """The full (B, B) dominance mask ``pareto_front`` used before its
+    block sweep: the reference its output must equal, index for index."""
+    import warnings
+    objs = np.asarray(objectives, np.float64)
+    finite = np.isfinite(objs).all(axis=1)
+    if not finite.all():
+        warnings.warn("pareto_front: non-finite rows", RuntimeWarning)
+        if not finite.any():
+            return np.zeros(0, dtype=np.int64)
+    rows = np.nonzero(finite)[0]
+    sub = objs[rows]
+    m = sub.shape[1]
+    order = np.lexsort(tuple(sub[:, j] for j in range(m - 1, -1, -1)))
+    ss = sub[order]
+    dom = (ss[None, :, :] <= ss[:, None, :]).all(axis=2)
+    dom &= np.tri(len(ss), k=-1, dtype=bool)
+    return np.asarray(rows[order[~dom.any(axis=1)]], dtype=np.int64)
+
+
+def _realistic_objectives(n, seed):
+    """(n, 3) latency-, energy- and cost-like objectives of log-uniform θ
+    over the default knob box, shaped like an explore call's."""
+    rng = np.random.default_rng(seed)
+    kt = random_candidates(DEFAULT_SPACE, n, seed=seed).astype(np.float64)
+    k = kt.shape[1]
+    a, e, w = (rng.uniform(0.1, 1.0, k) for _ in range(3))
+    latency = (a / kt).sum(axis=1) / a.sum() + 0.3 / kt[:, :2].min(axis=1)
+    energy = (e * np.sqrt(kt)).sum(axis=1) / e.sum() + 0.2 * latency
+    cost = (w / kt).sum(axis=1)
+    return np.stack([latency, energy, cost], axis=1)
+
+
+def _front_case(kind, b, m, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "uniform":
+        return rng.uniform(0.0, 1.0, (b, m))
+    objs = rng.integers(0, 5, (b, m)).astype(np.float64)   # heavy ties
+    if b > 1:                                               # exact duplicates
+        objs[rng.integers(0, b, b // 3)] = objs[rng.integers(0, b, b // 3)]
+    if kind == "nonfinite":
+        bad = rng.integers(0, b, max(1, b // 8))
+        objs[bad, rng.integers(0, m, bad.size)] = rng.choice(
+            [np.nan, np.inf, -np.inf], bad.size)
+    return objs
+
+
+_FRONT_CASES = [(kind, b, m) for kind in ("uniform", "ties", "nonfinite")
+                for b in (1, 2, 31, 32, 33, 63, 64, 65, 257, 1024)
+                for m in (2, 3, 4)] + [("realistic", 1024, 3)]
+
+
+@pytest.mark.parametrize("kind,b,m", _FRONT_CASES,
+                         ids=[f"{k}-{b}x{m}" for k, b, m in _FRONT_CASES])
+def test_pareto_front_equals_full_dominance_mask(kind, b, m):
+    """The block sweep returns the same indices in the same order as the
+    (B, B) dominance mask, across block boundaries (block size 32), on
+    ties, duplicates, non-finite rows and a sweep-shaped 1024 x 3 set."""
+    import warnings
+    seed = 1000 * b + 10 * m + len(kind)
+    objs = (_realistic_objectives(b, seed) if kind == "realistic"
+            else _front_case(kind, b, m, seed))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        want = _pareto_front_mask(objs)
+    if np.isfinite(objs).all():
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            got = pareto_front(objs)
+    else:
+        with pytest.warns(RuntimeWarning, match="non-finite"):
+            got = pareto_front(objs)
+    assert got.dtype == np.int64
+    assert np.array_equal(got, want), (got, want)
+
+
 def test_baseline_candidate_has_unit_latency(explorer):
     """Normalization is self-consistent: the θ = 1 candidate scores exactly
     latency 1.0 because Explorer.baselines comes from the same compiled
@@ -174,6 +250,17 @@ def test_explore_is_deterministic(explorer):
     r2 = explorer.explore(cand)
     assert np.array_equal(r1.cycles, r2.cycles)
     assert np.array_equal(r1.pareto, r2.pareto)
+
+
+def test_explore_counts_ranked_and_kept_rows(explorer):
+    before = explorer.stats()
+    res = explorer.explore(random_candidates(explorer.space, 48, seed=11))
+    after = explorer.stats()
+    assert after["pareto_calls"] == before["pareto_calls"] + 1
+    assert after["pareto_rows"] == before["pareto_rows"] + 48
+    assert after["pareto_kept"] == before["pareto_kept"] + len(res.pareto)
+    assert after["mean_front"] == pytest.approx(
+        after["pareto_kept"] / after["pareto_calls"])
 
 
 # ---------------------------------------------------------------------------

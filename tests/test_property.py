@@ -165,10 +165,20 @@ def test_condensed_soft_bounds_for_random_theta(scenario, tau, seed):
 # ---------------------------------------------------------------------------
 
 # a coarse grid of finite objective values: duplicates and exact ties are
-# *likely*, which is exactly the regime where frontier bugs hide
+# *likely*, which is exactly the regime where frontier bugs hide.  Row
+# counts are drawn uniformly up to 256, so inputs often span several of
+# pareto_front's 32-row sweep blocks
 _objective = st.integers(0, 8).map(lambda v: v / 4.0)
-_obj_rows = st.lists(st.tuples(_objective, _objective), min_size=1,
-                     max_size=40).map(lambda r: np.asarray(r, np.float64))
+_MAX_ROWS = 256
+
+
+def _rows_of(row):
+    return st.integers(1, _MAX_ROWS).flatmap(
+        lambda n: st.lists(row, min_size=n, max_size=n)).map(
+            lambda r: np.asarray(r, np.float64))
+
+
+_obj_rows = _rows_of(st.tuples(_objective, _objective))
 
 
 def _dominates(a, b):
@@ -224,9 +234,7 @@ def test_pareto_front_keeps_exactly_one_of_duplicates(objs):
 
 # the same invariants in 3 objectives — (latency, energy, cost), the
 # frontier Explorer.explore and serve._rank actually rank
-_obj_rows3 = st.lists(st.tuples(_objective, _objective, _objective),
-                      min_size=1, max_size=40).map(
-                          lambda r: np.asarray(r, np.float64))
+_obj_rows3 = _rows_of(st.tuples(_objective, _objective, _objective))
 
 
 @given(_obj_rows3)

@@ -90,6 +90,12 @@ def test_explore_spans_run_in_order(traced_explore):
             < start["packed.fetch"])
 
 
+def test_the_pareto_span_carries_the_rows_it_ranks(traced_explore):
+    _, _, spans = traced_explore
+    (args,) = [a for n, _, _, a in spans if n == "explore.pareto"]
+    assert int(args["rows"]) == BATCH
+
+
 def test_compiled_evaluator_names_each_bucket_and_the_composition(ex):
     pm = ex.packed_matrix()
     n_buckets = pm.stats()["buckets"]
