@@ -553,7 +553,7 @@ def pareto_front(objectives: np.ndarray) -> np.ndarray:
         best = np.minimum.accumulate(np.concatenate([[np.inf], rest[:-1, 0]]))
         return np.asarray(rows[order[rest[:, 0] < best]], dtype=np.int64)
     todo = np.arange(len(rest))     # sorted rows no kept row dominates yet
-    kept = []
+    kept = [todo[:0]]               # a 0-row input keeps nothing
     while todo.size:
         blk, todo = todo[:_PARETO_BLOCK], todo[_PARETO_BLOCK:]
         b = rest[blk]

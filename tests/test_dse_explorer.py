@@ -233,6 +233,13 @@ def test_pareto_front_equals_full_dominance_mask(kind, b, m):
     assert np.array_equal(got, want), (got, want)
 
 
+@pytest.mark.parametrize("m", [2, 3, 4])
+def test_pareto_front_of_no_rows_is_empty(m):
+    got = pareto_front(np.zeros((0, m)))
+    assert got.dtype == np.int64 and got.size == 0
+    assert np.array_equal(got, _pareto_front_mask(np.zeros((0, m))))
+
+
 def test_baseline_candidate_has_unit_latency(explorer):
     """Normalization is self-consistent: the θ = 1 candidate scores exactly
     latency 1.0 because Explorer.baselines comes from the same compiled
