@@ -502,7 +502,7 @@ def grid_candidates(space: DesignSpace, points: int = 4) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-# rows ranked per step of pareto_front's block sweep (M >= 3)
+# rows ranked per step of pareto_front's block sweep
 _PARETO_BLOCK = 32
 
 
@@ -520,11 +520,9 @@ def pareto_front(objectives: np.ndarray) -> np.ndarray:
     The sweep visits rows in lexicographic order (first objective primary)
     and drops a row iff some earlier sorted row weakly dominates it (<= in
     every objective).  An earlier sorted row is never worse in the first
-    objective, so only the others are compared.  On 2-objective input this
-    is the classic best-so-far scan: a row stays iff its second objective
-    is strictly below every earlier one — O(B).
+    objective, so only the others are compared.
 
-    With M >= 3 the sorted rows are ranked in blocks of ``_PARETO_BLOCK``.
+    The sorted rows are ranked in blocks of ``_PARETO_BLOCK``.
     Checking kept rows only is exact (<= is transitive: whatever
     dominates a dominated row also dominates its victims).  So each block
     is tested within itself under a lower-triangle mask, and its
@@ -549,9 +547,6 @@ def pareto_front(objectives: np.ndarray) -> np.ndarray:
     m = sub.shape[1]
     order = np.lexsort(tuple(sub[:, j] for j in range(m - 1, -1, -1)))
     rest = sub[order, 1:]           # objective 0 is sorted: never compared
-    if m == 2:
-        best = np.minimum.accumulate(np.concatenate([[np.inf], rest[:-1, 0]]))
-        return np.asarray(rows[order[rest[:, 0] < best]], dtype=np.int64)
     todo = np.arange(len(rest))     # sorted rows no kept row dominates yet
     kept = [todo[:0]]               # a 0-row input keeps nothing
     while todo.size:
@@ -722,14 +717,13 @@ class Explorer:
         aggregate."""
         return [cs.stats_row() for cs in self.compiled]
 
-    def stats(self) -> Dict[str, float]:
+    def stats(self) -> Dict[str, int]:
         """Running counters of ``explore``'s Pareto ranking: calls, rows
-        ranked and front rows kept, and the mean front size per call (the
+        ranked and front rows kept (kept over calls is the mean front size
         F that ``pareto_front``'s sweep scales with)."""
-        r = dict(self._ranking)
+        r = self._ranking
         return {"pareto_calls": r["calls"], "pareto_rows": r["rows"],
-                "pareto_kept": r["kept"],
-                "mean_front": r["kept"] / max(1, r["calls"])}
+                "pareto_kept": r["kept"]}
 
     # -- cost/area proxy ----------------------------------------------------
 
